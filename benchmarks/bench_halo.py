@@ -1,7 +1,9 @@
 """§II-A inter-core register sharing: halo-exchange vs global-buffer
 (all-gather) collective bytes on the 2D mesh — the Fig. 3(b) 3× memory-
-read-reduction analogue.  Runs in a subprocess with 4 fake devices so the
-benchmark process itself keeps a single-device view."""
+read-reduction analogue.  The bytes are counted in the compiled HLO of a
+child process pinned to the CPU with 16 fake devices: it never needs the
+accelerator, so it runs whether or not this process holds one, and this
+process never touches jax."""
 from __future__ import annotations
 
 import json
@@ -49,6 +51,7 @@ def main(report=print):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), "src")
+    env["JAX_PLATFORMS"] = "cpu"
     p = subprocess.run([sys.executable, "-c", _CODE], env=env,
                        capture_output=True, text=True, timeout=900)
     line = [l for l in p.stdout.splitlines() if l.startswith("{")][-1]

@@ -18,7 +18,7 @@ Invocation forms:
   PYTHONPATH=src:. python -m benchmarks.bench_serve \\
       --force-host-devices 4 --mesh-shape 4                        # sharded
   PYTHONPATH=src:. python -m benchmarks.bench_serve --scaling 1,2,4,8 \\
-      --json BENCH_serve.json                  # device-scaling subprocesses
+      --json BENCH_scaling.json     # device-scaling subprocesses, nothing else
 
 Every report also carries a ``map`` section (annealed MAP/MPE queries/s
 under assignment-stability retirement, :func:`run_map`) and a
@@ -36,9 +36,8 @@ synchronous baseline — with a bitwise identity check of queued vs
 
 ``--json`` emits a machine-readable report (queries/s, MSample/s,
 **ESS/s** — effective samples per second, the honest analogue of the
-paper's MSample/s — bits/sample, cold/warm, stream metrics, and — with
-``--scaling`` — per-device-count throughput from forced-host
-subprocesses) so CI can track the perf trajectory;
+paper's MSample/s — bits/sample, cold/warm, stream metrics) so CI can
+track the perf trajectory;
 ``benchmarks/check_serve_regression.py`` gates CI on it against
 ``benchmarks/baselines/BENCH_serve.json``.  The report carries the
 engine's ``retirement`` mode so the gate can refuse to compare a
@@ -54,6 +53,10 @@ capacity against a per-tenant token-bucket quota set to capacity — the
 report carries served p50/p99 latency and the shed rate (429/503), and
 ``check_serve_regression`` holds the shed-rate floor plus a bounded
 p99 (shedding at the front door instead of queue collapse).
+
+``--scaling`` runs only per-device-count throughput points, each in a
+child process (forced-host devices on the CPU); the parent stays off
+jax so that on an accelerator each child can own the chip.
 
 ``--diagnostics-json`` additionally runs the same traffic under both
 retirement rules (``legacy`` plain split-R̂ vs ``rank`` rank-R̂ + ESS)
@@ -975,7 +978,8 @@ def _cli(argv=None):
                     help="serve mesh, e.g. 4 or 2x2")
     ap.add_argument("--scaling", default="",
                     help="comma-separated forced-host device counts, "
-                         "e.g. 1,2,4,8 — runs one subprocess per count")
+                         "e.g. 1,2,4,8 — runs one subprocess per count "
+                         "and nothing else in this process")
     ap.add_argument("--overload", action="store_true",
                     help="add the HTTP front-end overload benchmark: "
                          "bitwise served-vs-answer_batch identity, then "
@@ -994,9 +998,21 @@ def _cli(argv=None):
                          "stats()/metrics snapshot here (CI artifact)")
     args = ap.parse_args(argv)
 
+    if args.scaling:
+        # each point is a child process that owns the device(s); the
+        # parent never touches jax, or the children could not get the chip
+        counts = [int(s) for s in args.scaling.split(",") if s]
+        # scaling points are always smoke-sized: one datapoint per device
+        # count, each paying its own interpreter + XLA compile
+        _write_report({"suite": "serve_scaling",
+                       "scaling": scaling(counts, smoke=True)}, args.json)
+        return
+
     if args.force_host_devices:
         from repro.launch.mesh import force_host_devices
         force_host_devices(args.force_host_devices)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     mesh_shape = None
     if args.mesh_shape:
@@ -1016,17 +1032,16 @@ def _cli(argv=None):
         rep["overload"] = run_overload()
     if args.million_spin:
         rep["million_spin"] = run_million_spin(side=args.million_spin_side)
-    if args.scaling:
-        counts = [int(s) for s in args.scaling.split(",") if s]
-        # scaling points are always smoke-sized: one datapoint per device
-        # count, each paying its own interpreter + XLA compile
-        rep["scaling"] = scaling(counts, smoke=True)
-    if args.json == "-":
+    _write_report(rep, args.json)
+
+
+def _write_report(rep: dict, dest: str) -> None:
+    if dest == "-":
         print(json.dumps(rep))
-    elif args.json:
-        with open(args.json, "w") as f:
+    elif dest:
+        with open(dest, "w") as f:
             json.dump(rep, f, indent=2)
-        print(f"# wrote {args.json}")
+        print(f"# wrote {dest}")
 
 
 if __name__ == "__main__":

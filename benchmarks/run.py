@@ -24,6 +24,10 @@ from benchmarks import (
     bench_sota_table,
 )
 
+# Suites run in this process and share its device.  None may start a
+# child that needs the accelerator: this process holds it once a suite
+# has run (bench_halo's child is pinned to the CPU; device-scaling runs
+# are `python -m benchmarks.bench_serve --scaling`, a process of their own).
 SUITES = [
     ("schmoo", bench_schmoo),          # Fig. 6
     ("ky_vs_cdf", bench_ky_vs_cdf),    # §II-B 3x claim

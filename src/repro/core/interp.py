@@ -40,7 +40,7 @@ import numpy as np
 class InterpTable:
     """Piecewise-linear LUT over [lo, hi] with 2**m segments."""
 
-    table: jax.Array      # (2**m + 1,) float32 node values
+    table: jax.Array      # (2**m + 1,) float32 nodes; lut_rows form in a kernel
     lo: float
     hi: float
     m: int                # log2 #segments
@@ -48,8 +48,13 @@ class InterpTable:
     @staticmethod
     def build(fn: Callable, lo: float, hi: float, m: int = 8) -> "InterpTable":
         xs = np.linspace(lo, hi, (1 << m) + 1, dtype=np.float64)
-        tab = jnp.asarray(np.asarray(fn(xs), dtype=np.float32))
+        # host array: building a table at import must not claim a device
+        tab = np.asarray(fn(xs), dtype=np.float32)
         return InterpTable(table=tab, lo=float(lo), hi=float(hi), m=m)
+
+    def rows(self) -> jax.Array:
+        """The node table as :func:`lut_rows`, the form a kernel reads."""
+        return lut_rows(self.table, 1 << self.m)
 
     def __call__(self, x: jax.Array) -> jax.Array:
         """Interpolate fn(x); inputs are clamped to [lo, hi]."""
@@ -59,8 +64,11 @@ class InterpTable:
         t = jnp.clip((x - self.lo) * scale, 0.0, float(n))
         idx = jnp.minimum(t.astype(jnp.int32), n - 1)  # "IU.address"
         frac = t - idx.astype(jnp.float32)             # "offset"
-        y0 = jnp.take(self.table, idx, mode="clip")
-        y1 = jnp.take(self.table, idx + 1, mode="clip")
+        if self.table.ndim == 1:   # XLA: a plain gather
+            y0 = jnp.take(self.table, idx, mode="clip")
+            y1 = jnp.take(self.table, idx + 1, mode="clip")
+        else:                      # in a kernel: lut_rows form, lane gathers
+            y0, y1 = lut_pair(self.table, idx)
         return y0 + frac * (y1 - y0)           # single FMA, as in the IU
 
     def max_abs_error(self, fn: Callable, probe: int = 65536) -> float:
@@ -68,6 +76,45 @@ class InterpTable:
         exact = np.asarray(fn(xs.astype(np.float64)))
         approx = np.asarray(jax.jit(self.__call__)(xs))
         return float(np.max(np.abs(exact - approx)))
+
+
+LUT_LANES = 128   # one TPU vreg row: the width of an in-register gather
+
+
+def lut_rows(table: jax.Array, n: int) -> jax.Array:
+    """``(2, W)`` rows ``[table[0:W], table[1:W + 1]]``, W = n rounded up to 128.
+
+    The two node reads of an interpolation, laid out so both come from
+    128-aligned chunks (``table`` is the ``(n + 1,)`` node array).
+    """
+    width = -(-n // LUT_LANES) * LUT_LANES
+    tab = jnp.pad(table, (0, width + 1 - table.shape[0]))
+    return jnp.stack([tab[:width], tab[1:width + 1]])
+
+
+def lut_pair(rows: jax.Array, idx: jax.Array):
+    """``(table[idx], table[idx + 1])`` elementwise, from :func:`lut_rows`.
+
+    ``idx`` is an int32 (rows, 128) tile, as in the fused sweep kernel.
+    Each 128-entry chunk of the rows is broadcast over the tile, read
+    with a lane gather (``take_along_axis`` on the last axis, Mosaic's
+    in-vreg ``tpu.dynamic_gather``) and kept where ``idx`` falls in that
+    chunk.  A table read is exact, so this returns the same floats as
+    the ``jnp.take`` of the XLA path, bit for bit.
+    """
+    chunk = idx >> 7                        # idx // LUT_LANES
+    lane = idx & (LUT_LANES - 1)
+    y0 = y1 = jnp.zeros(idx.shape, rows.dtype)
+    for c in range(rows.shape[-1] // LUT_LANES):
+        in_c = chunk == c
+        part = rows[:, c * LUT_LANES:(c + 1) * LUT_LANES]
+        got0, got1 = (
+            jnp.take_along_axis(jnp.broadcast_to(part[r:r + 1], idx.shape),
+                                lane, axis=-1, mode="promise_in_bounds")
+            for r in (0, 1))
+        y0 = jnp.where(in_c, got0, y0)
+        y1 = jnp.where(in_c, got1, y1)
+    return y0, y1
 
 
 # Pytree registration: the node values are traced data, the range/shape
@@ -151,12 +198,16 @@ def masked_exp_weights(
     for ``k <= 23``), and ``exp`` evaluated through the IU LUT when
     ``use_iu``.  ``logw`` is (..., L); ``card`` broadcasts against the
     batch axes (per-node cardinalities for BN/sparse plans, a scalar L
-    for dense grids).  This exact function runs both in the XLA sampler
+    for dense grids; a ``(..., 1)`` column as the fused kernel passes
+    it).  This exact function runs both in the XLA sampler
     path (via ``pgm.compile.ky_weights``) and *inside* the fused Pallas
     kernel, so the two are bitwise-comparable by construction.
     """
-    ls = jnp.arange(logw.shape[-1], dtype=jnp.int32)
-    logw = jnp.where(ls < card[..., None], logw, mask_value)
+    card = jnp.asarray(card, jnp.int32)
+    if card.ndim < logw.ndim:
+        card = card[..., None]
+    ls = jax.lax.broadcasted_iota(jnp.int32, logw.shape, logw.ndim - 1)
+    logw = jnp.where(ls < card, logw, mask_value)
     z = logw - jnp.max(logw, axis=-1, keepdims=True)
     if use_iu:
         y = (table or _EXP_DEFAULT)(z)

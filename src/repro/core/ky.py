@@ -25,9 +25,10 @@ the exact per-lane count.
 Bit-stream contract (see ``docs/kernels.md``): this module uses a
 **per-lane bit cursor** — lane ``i`` reads bit ``t_i`` of its own uint32
 word row, and ``t_i`` advances only while lane ``i`` is still walking.
-:func:`ky_walk` is the cursor-and-walk core, factored out so the fused
-Pallas sweep kernel (``kernels/fused_sweep.py``) can run the *identical*
-code on the identical pre-generated words — which is what makes the
+:func:`ky_walk` is the cursor-and-walk core, and :func:`ky_walk_tile`
+the same body on (b, 1) columns, so the fused Pallas sweep kernel
+(``kernels/fused_sweep.py``) runs the *identical* walk on the identical
+pre-generated words — which is what makes the
 engine's ``sampler="pallas"`` path bitwise-interchangeable with
 ``sampler="xla"``.  The standalone KY kernel/oracle pair
 (``kernels/ky_sampler.py`` / ``kernels/ref.py::ky_ref``) instead shares a
@@ -67,19 +68,61 @@ def ky_walk(flat: jax.Array, bit_words: jax.Array) -> KYResult:
         under the per-lane cursor (bit ``t_i``, advanced only while the
         lane is active).  The walk budget is ``W * 32`` bits per lane.
 
-    This is the sampling core behind :func:`ky_sample`; the fused Pallas
-    sweep kernel (``kernels/fused_sweep.py``) calls it verbatim inside
-    the kernel body, so both consume identical bit positions and return
-    bitwise-identical results for identical inputs.  Returns a
-    :class:`KYResult` with (b,) fields.
+    This is the sampling core behind :func:`ky_sample`.  Returns a
+    :class:`KYResult` with (b,) fields.  :func:`ky_walk_tile` runs the
+    same body with every per-lane value a (b, 1) column, the layout the
+    fused Pallas sweep kernel (``kernels/fused_sweep.py``) needs inside
+    its body; the two consume identical bit positions and return
+    bitwise-identical results for identical inputs.
+    """
+    r = _walk(flat, bit_words, tile=False)
+    return r._replace(ok=r.ok > 0)
+
+
+def ky_walk_tile(flat: jax.Array, bit_words: jax.Array) -> KYResult:
+    """:func:`ky_walk` on a (b, n) tile, every per-lane value a (b, 1) column.
+
+    The form the Mosaic TPU compiler lowers: 2-D loop carries, an int32
+    ``ok`` (1 = done), and the inclusive prefix sum over outcomes as an
+    f32 matmul with an upper triangle of ones (exact: the summands are
+    0/1 bits and n < 2**24).
+    """
+    return _walk(flat, bit_words, tile=True)
+
+
+def _walk(flat: jax.Array, bit_words: jax.Array, *, tile: bool) -> KYResult:
+    """The walk of :func:`ky_walk` / :func:`ky_walk_tile`.
+
+    Per-lane values are (b, 1) columns when ``tile`` and (b,) vectors
+    otherwise: XLA pads a (b, 1) int32 array to 128 lanes on the TPU, so
+    the XLA path keeps its loop carries 1-D.  Every step is integer
+    arithmetic or an exact prefix sum, so both layouts give the same bits.
     """
     flat = jnp.asarray(flat, jnp.int32)
     b, n = flat.shape
     budget = int(bit_words.shape[-1]) * 32
+    iota = jax.lax.broadcasted_iota(jnp.int32, (b, n), 1)
 
-    total = jnp.sum(flat, axis=-1)
+    def col(v):      # a per-lane value against the (b, n) tile
+        return v if tile else v[:, None]
+
+    def lane_sum(x):
+        return jnp.sum(x, axis=-1, keepdims=tile)
+
+    def first_index(mask):   # first True along the outcomes; n where none
+        return jnp.min(jnp.where(mask, iota, n), axis=-1, keepdims=tile)
+
+    def prefix_sum(x):
+        if not tile:
+            return jnp.cumsum(x, axis=-1)
+        upper = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+                 <= jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+        return jnp.dot(x.astype(jnp.float32), upper.astype(jnp.float32),
+                       preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    total = lane_sum(flat)
     # Defensive: an all-zero row would hang the walk; force outcome 0.
-    flat = jnp.where((total == 0)[:, None] & (jnp.arange(n) == 0)[None, :], 1, flat)
+    flat = jnp.where((col(total) == 0) & (iota == 0), 1, flat)
     total = jnp.maximum(total, 1)
 
     k_lvl = jnp.maximum(ceil_log2(total), 1)      # per-lane K (>=1)
@@ -87,32 +130,33 @@ def ky_walk(flat: jax.Array, bit_words: jax.Array) -> KYResult:
 
     def cond(state):
         done, _, _, _, t, _ = state
-        return (~jnp.all(done)) & (jnp.max(jnp.where(done, 0, t)) < budget - 1)
+        return ((jnp.min(done) == 0)
+                & (jnp.max(jnp.where(done > 0, 0, t)) < budget - 1))
 
     def body(state):
         done, d, c, res, t, att = state
-        active = ~done
+        active = done == 0
         bit = rng_lib.get_bit(bit_words, jnp.minimum(t, budget - 1))
         d2 = 2 * d + (1 - bit)
         # Bit-plane column at level c: MSB-first bit of each weight.
-        shift = (k_lvl - 1 - c)[:, None]
-        col = jnp.where(shift >= 0, (flat >> shift) & 1, 0)
-        rcol = jnp.where(shift[:, 0] >= 0, (reject_w >> shift[:, 0]) & 1, 0)
-        cum = jnp.cumsum(col, axis=-1)
-        colsum = cum[:, -1] + rcol
+        shift = k_lvl - 1 - c
+        safe = jnp.maximum(shift, 0)
+        plane = jnp.where(col(shift) >= 0, (flat >> col(safe)) & 1, 0)
+        rcol = jnp.where(shift >= 0, (reject_w >> safe) & 1, 0)
+        cum = prefix_sum(plane)
+        colsum = lane_sum(plane) + rcol
         hit = d2 < colsum
         # first index with cum == d2+1; if none (leaf is the rejection pad),
         # sel lands past the real outcomes.
-        ge = cum >= (d2 + 1)[:, None]
-        sel = jnp.argmax(ge, axis=-1)
-        is_real = hit & ge[jnp.arange(b), sel]
+        sel = first_index(cum >= col(d2) + 1)
+        is_real = hit & (sel < n)
         is_rej = hit & ~is_real
         # level overflow can't occur with exact pad mass, but guard anyway
         overflow = (~hit) & (c + 1 >= k_lvl)
         restart = (is_rej | overflow) & active
         finish = is_real & active
-        done2 = done | finish
-        res2 = jnp.where(finish, sel.astype(jnp.int32), res)
+        done2 = jnp.where(finish, 1, done)
+        res2 = jnp.where(finish, sel, res)
         d3 = jnp.where(restart, 0, jnp.where(hit, d, d2 - colsum))
         c2 = jnp.where(restart, 0, jnp.where(hit, c, c + 1))
         t2 = t + active.astype(jnp.int32)
@@ -123,17 +167,19 @@ def ky_walk(flat: jax.Array, bit_words: jax.Array) -> KYResult:
     # deterministic: p = total/2^K = 1.0 has no fractional DDG expansion
     # (hypothesis-found corner, e.g. w = [0, 2]); resolve them up front
     # with zero random bits, exactly like the hardware's bypass path.
-    argmax0 = jnp.argmax(flat, axis=-1).astype(jnp.int32)
-    deterministic = jnp.max(flat, axis=-1) == total
+    top = jnp.max(flat, axis=-1, keepdims=tile)
+    argmax0 = first_index(flat == col(top))
+    deterministic = (top == total).astype(jnp.int32)
 
     # Derive the carry init from the inputs so it inherits any varying
     # manual axes when called inside shard_map (JAX >= 0.7 VMA rules).
-    zeros = flat[:, 0] * 0 + (bit_words[:, 0] * 0).astype(jnp.int32)
-    state = (deterministic, zeros, zeros,
-             jnp.where(deterministic, argmax0, 0), zeros, zeros + 1)
+    lane = slice(0, 1) if tile else 0
+    zeros = flat[:, lane] * 0 + (bit_words[:, lane] * 0).astype(jnp.int32)
+    state = (zeros + deterministic, zeros, zeros,
+             jnp.where(deterministic > 0, argmax0, 0), zeros, zeros + 1)
     done, _, _, res, t, att = jax.lax.while_loop(cond, body, state)
     # Fallback for (astronomically unlikely) budget exhaustion.
-    res = jnp.where(done, res, jnp.argmax(flat, axis=-1).astype(jnp.int32))
+    res = jnp.where(done > 0, res, argmax0)
     return KYResult(sample=res, bits_used=t, attempts=att, ok=done)
 
 

@@ -29,13 +29,20 @@ def random_bit_words(key: jax.Array, shape: tuple, max_bits: int) -> jax.Array:
 def get_bit(words: jax.Array, idx: jax.Array) -> jax.Array:
     """Extract bit ``idx`` (0-based) from the per-lane word stream.
 
-    ``words``: (..., W) uint32;  ``idx``: (...,) int32 broadcastable.
-    Returns int32 in {0, 1}.
+    ``words``: (..., W) uint32;  ``idx``: int32, one cursor per lane,
+    shaped (...,) or (..., 1).  Returns int32 in {0, 1} shaped like
+    ``idx``.  The bit is taken from every word and the cursor's word kept
+    by a one-hot select and a sum over the W axis, not a gather, so the
+    same code lowers inside a Mosaic kernel (where a per-lane gather
+    along the word axis does not) and in XLA.
     """
-    word_ix = idx // 32
-    bit_ix = idx % 32
-    w = jnp.take_along_axis(words, word_ix[..., None].astype(jnp.int32), axis=-1)[..., 0]
-    return ((w >> bit_ix.astype(jnp.uint32)) & jnp.uint32(1)).astype(jnp.int32)
+    keep = idx.ndim == words.ndim
+    cur = idx if keep else idx[..., None]
+    bits = ((words >> (cur & 31).astype(jnp.uint32)) & jnp.uint32(1)
+            ).astype(jnp.int32)
+    word = jax.lax.broadcasted_iota(jnp.int32, words.shape, words.ndim - 1)
+    return jnp.sum(jnp.where(word == cur >> 5, bits, 0), axis=-1,
+                   keepdims=keep)
 
 
 # ----------------------------------------------------------------------------

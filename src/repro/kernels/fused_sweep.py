@@ -17,7 +17,8 @@ No intermediate (weights, klvl, rej) tensors ever round-trip through HBM
 
 Bitwise contract (docs/kernels.md): the kernel body calls the *same*
 functions the XLA path uses — ``core.interp.masked_exp_weights`` for the
-distribution-generation tail and ``core.ky.ky_walk`` for the DDG walk —
+distribution-generation tail and ``core.ky.ky_walk_tile``, the body of
+``core.ky.ky_walk`` on (b, 1) columns, for the DDG walk —
 on bit words pre-generated outside the kernel by the same
 ``core.rng.random_bit_words(key, (b,), 992)`` call that
 ``core.ky.ky_sample`` makes internally.  ``sampler="pallas"`` is
@@ -39,7 +40,9 @@ practice (asserted or noted):
 
 ``interpret=True`` (the default on non-TPU backends) runs the kernel
 through the Pallas interpreter — the CPU/CI escape hatch shared with
-``kernels/interp_lut.py`` and ``kernels/ky_sampler.py``.
+``kernels/interp_lut.py`` and ``kernels/ky_sampler.py``.  On a TPU the
+kernel is always compiled; ``tests/test_tpu_compile.py`` compiles it
+for a described v5e at the Penguin and Art widths.
 """
 from __future__ import annotations
 
@@ -48,10 +51,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import interp as interp_lib
 from repro.core import rng as rng_lib
-from repro.core.ky import KYResult, ky_walk
+from repro.core.ky import KYResult, ky_walk, ky_walk_tile
 
 # masked-label log-weight floor — see core.interp.MASK_NEG
 MASK_NEG = interp_lib.MASK_NEG
@@ -62,7 +66,11 @@ MAX_FUSED_K = 23
 
 
 def _resolve_interpret(interpret: bool | None) -> bool:
-    """Default to the interpreter off-TPU (CPU CI), compiled on TPU."""
+    """Default to the interpreter off-TPU (the CPU test path), compiled on TPU.
+
+    On a TPU the kernel is always compiled: a kernel Mosaic refuses
+    raises there, it never falls back to the interpreter.
+    """
     if interpret is None:
         return jax.default_backend() != "tpu"
     return bool(interpret)
@@ -75,20 +83,20 @@ def _fused_kernel(logw_ref, card_ref, words_ref, tab_ref,
     """One (block_b, n_pad) tile: weights never leave VMEM.
 
     The body is just the two shared helpers — ``masked_exp_weights``
-    builds the int32 weight tile in registers/VMEM, ``ky_walk`` samples
-    from it in place.  The LUT block is pinned (index map (0, 0)), the
-    analogue of the IU's dedicated table registers.
+    builds the int32 weight tile in registers/VMEM, ``ky_walk_tile``
+    samples from it in place.  The LUT block is pinned (index map
+    (0, 0)), the analogue of the IU's dedicated table registers; it
+    arrives already in ``lut_rows`` form.
     """
-    table = interp_lib.InterpTable(
-        table=tab_ref[...][0], lo=lo, hi=hi, m=m)
+    table = interp_lib.InterpTable(table=tab_ref[...], lo=lo, hi=hi, m=m)
     w = interp_lib.masked_exp_weights(
-        logw_ref[...], card_ref[...][:, 0], k,
+        logw_ref[...], card_ref[...], k,
         use_iu=use_iu, table=table, mask_value=mask_value)
-    r = ky_walk(w, words_ref[...])
-    s_ref[...] = r.sample[:, None]
-    bits_ref[...] = r.bits_used[:, None]
-    att_ref[...] = r.attempts[:, None]
-    ok_ref[...] = r.ok[:, None]
+    r = ky_walk_tile(w, words_ref[...])
+    s_ref[...] = r.sample
+    bits_ref[...] = r.bits_used
+    att_ref[...] = r.attempts
+    ok_ref[...] = r.ok
 
 
 def _pad_up(x: int, mult: int) -> int:
@@ -98,7 +106,7 @@ def _pad_up(x: int, mult: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("k", "use_iu", "mask_value", "max_attempts", "block_b",
-                     "interpret"))
+                     "interpret", "mesh"))
 def fused_gibbs_sample(
     key: jax.Array,
     logw: jax.Array,        # (b, n) float32 gathered log-weights
@@ -111,6 +119,7 @@ def fused_gibbs_sample(
     max_attempts: int = 32,
     block_b: int = 256,
     interpret: bool | None = None,
+    mesh: Mesh | None = None,
 ) -> KYResult:
     """Fused distribution-generation + KY sampling, one lane per row.
 
@@ -123,6 +132,11 @@ def fused_gibbs_sample(
     tests pin.  Returns a :class:`KYResult` with (b,) fields.
 
     ``interpret=None`` auto-selects the Pallas interpreter off-TPU.
+
+    ``mesh``: the lanes are sharded over the mesh's first axis (the serve
+    mesh's "batch") and each device runs the kernel on its own lanes —
+    XLA cannot partition a Mosaic kernel itself.  Every lane's walk reads
+    only its own bit words, so the result is the same bit for bit.
     """
     if k > MAX_FUSED_K:
         raise ValueError(
@@ -134,12 +148,32 @@ def fused_gibbs_sample(
     table = table or interp_lib._EXP_DEFAULT
 
     # Bit words are generated OUTSIDE the kernel at the true lane count —
-    # the exact stream ky_sample(key, ...) would draw.  (Generating at the
-    # padded count would change every word: threefry pairs counters by
-    # total count.)  Padding lanes get zero words; they are deterministic
-    # rows and never read a bit.
+    # the exact stream ky_sample(key, ...) would draw.  Under JAX's
+    # partitionable threefry each word depends only on its (lane, word)
+    # position, so the padded count would give the same first b rows; the
+    # true count just draws fewer words.  Padding lanes get zero words;
+    # they are deterministic rows and never read a bit.
     words = rng_lib.random_bit_words(key, (b,), 31 * max_attempts)
+    call = functools.partial(
+        _fused_call, k=k, use_iu=use_iu, lo=table.lo, hi=table.hi,
+        m=table.m, mask_value=float(mask_value), block_b=block_b,
+        interpret=_resolve_interpret(interpret))
+    args = (logw, card, words, table.rows())
+    if mesh is None:
+        out = call(*args)
+    else:
+        lanes = P(mesh.axis_names[0])
+        out = jax.shard_map(
+            call, mesh=mesh, in_specs=(lanes, lanes, lanes, P()),
+            out_specs=(lanes,) * 4, check_vma=False)(*args)
+    s, bits, att, ok = out
+    return KYResult(sample=s, bits_used=bits, attempts=att, ok=ok > 0)
 
+
+def _fused_call(logw, card, words, rows, *, k, use_iu, lo, hi, m,
+                mask_value, block_b, interpret):
+    """Pad ``b`` lanes to whole blocks, run the kernel, slice back."""
+    b, n = logw.shape
     block_b = max(8, int(block_b))
     b_pad = _pad_up(b, block_b)
     n_pad = _pad_up(n, 128)             # VPU lane width
@@ -152,36 +186,25 @@ def fused_gibbs_sample(
     card_p = jnp.pad(card, (0, b_pad - b), constant_values=1)[:, None]
     words_p = jnp.pad(words, ((0, b_pad - b), (0, 0)))
 
-    tab = table.table
-    t_pad = _pad_up(int(tab.shape[0]), 128)
-    tab2d = jnp.pad(tab, (0, t_pad - int(tab.shape[0])))[None, :]
-
     n_words = int(words.shape[-1])
-    grid = (b_pad // block_b,)
     kernel = functools.partial(
-        _fused_kernel, k=k, use_iu=use_iu,
-        lo=table.lo, hi=table.hi, m=table.m, mask_value=float(mask_value))
+        _fused_kernel, k=k, use_iu=use_iu, lo=lo, hi=hi, m=m,
+        mask_value=mask_value)
     block = pl.BlockSpec((block_b, 1), lambda i: (i, 0))
-    s, bits, att, ok = pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(b_pad // block_b,),
         in_specs=[
             pl.BlockSpec((block_b, n_pad), lambda i: (i, 0)),
             pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_b, n_words), lambda i: (i, 0)),
-            pl.BlockSpec((1, t_pad), lambda i: (0, 0)),
+            pl.BlockSpec(rows.shape, lambda i: (0, 0)),
         ],
         out_specs=[block, block, block, block],
-        out_shape=[
-            jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((b_pad, 1), jnp.bool_),
-        ],
-        interpret=_resolve_interpret(interpret),
-    )(logw_p, card_p, words_p, tab2d)
-    return KYResult(sample=s[:b, 0], bits_used=bits[:b, 0],
-                    attempts=att[:b, 0], ok=ok[:b, 0])
+        out_shape=[jax.ShapeDtypeStruct((b_pad, 1), jnp.int32)] * 4,
+        interpret=interpret,
+    )(logw_p, card_p, words_p, rows)
+    return tuple(o[:b, 0] for o in out)
 
 
 def fused_gibbs_sample_ref(

@@ -7,9 +7,7 @@ touches jax device state).  Single pod: (16, 16) = 256 chips as
 gradient all-reduce per step — DESIGN.md §6).
 
 ``make_serve_mesh`` builds the 1D/2D mesh the posterior query service
-(:mod:`repro.serve`) shards its chain-lane batches over; it sticks to
-the version-portable ``jax.sharding.Mesh`` constructor so the serve path
-also runs on jax installs without the explicit-mesh API.
+(:mod:`repro.serve`) shards its chain-lane batches over.
 """
 from __future__ import annotations
 
@@ -17,12 +15,7 @@ import os
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # explicit-mesh API (jax >= 0.6); training meshes require it
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 SERVE_AXES = ("batch", "model")
 
@@ -117,9 +110,6 @@ def make_pgm_mesh(rows: int = 4, cols: int = 4) -> Mesh:
     devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(f"pgm mesh needs {n} devices, have {len(devices)}")
-    if AxisType is None:  # pragma: no cover - older jax
-        return Mesh(np.asarray(devices[:n]).reshape(rows, cols),
-                    ("row", "col"))
     return jax.make_mesh((rows, cols), ("row", "col"),
                          devices=devices[:n],
                          axis_types=(AxisType.Auto, AxisType.Auto))

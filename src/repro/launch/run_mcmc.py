@@ -67,11 +67,13 @@ def main() -> None:
     import numpy as np
 
     from repro.configs.aia_paper import MCMC_CONFIGS
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.pgm import networks
     from repro.pgm.compile import compile_bayesnet, run_gibbs
     from repro.pgm.gibbs import init_labels, mrf_gibbs
     from repro.pgm.mesh_gibbs import make_mesh_gibbs_step, shard_mrf
 
+    enable_compile_cache()
     cfg = MCMC_CONFIGS[args.config]
     sweeps = args.sweeps or cfg.n_sweeps
     chains = args.chains or cfg.n_chains

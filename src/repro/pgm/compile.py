@@ -236,6 +236,7 @@ def _color_update(
     use_iu: bool,
     sampler: str = "xla",
     beta: jax.Array | None = None,   # traced inverse temperature, (B,) or scalar
+    mesh=None,                       # serve mesh the lane axis is sharded on
 ) -> tuple[jax.Array, BNSweepStats]:
     ls = jnp.arange(max_card, dtype=jnp.int32)            # (L,)
     nodes = jnp.asarray(plan.nodes)
@@ -283,7 +284,7 @@ def _color_update(
             card[None], logw.shape[:-1]).reshape(-1)
         res = fused_gibbs_sample(
             key, logw.reshape((-1, max_card)), lane_card,
-            k=k, use_iu=use_iu, table=_EXP)
+            k=k, use_iu=use_iu, table=_EXP, mesh=mesh)
     else:
         wts = ky_weights(logw, card, k, use_iu)
         res = ky_sample(key, wts.reshape((-1, max_card)))

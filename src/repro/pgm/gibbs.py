@@ -85,7 +85,7 @@ def site_weights(
     return _weights_from_energies(energies, k=k, table=table, use_iu=use_iu)
 
 
-@partial(jax.jit, static_argnames=("k", "use_iu", "sampler"))
+@partial(jax.jit, static_argnames=("k", "use_iu", "sampler", "mesh"))
 def checkerboard_halfstep(
     key: jax.Array,
     labels: jax.Array,          # (B, H, W) int32
@@ -98,6 +98,7 @@ def checkerboard_halfstep(
     use_iu: bool = True,
     sampler: str = "xla",
     beta: jax.Array | None = None,    # traced inverse temperature, (B,) or scalar
+    mesh=None,                        # serve mesh the chain axis is sharded on
 ) -> tuple[jax.Array, SweepStats]:
     """Resample all sites of one checkerboard color, all chains at once.
 
@@ -132,7 +133,7 @@ def checkerboard_halfstep(
             energies = unary[None] + neighbor_pair_energy(labels, pairwise)
         res = fused_gibbs_sample(
             key, (-energies).reshape((-1, l)), l, k=k, use_iu=use_iu,
-            table=_EXP)
+            table=_EXP, mesh=mesh)
     else:
         if energies is None:
             wts = site_weights(labels, unary, pairwise, k=k, use_iu=use_iu)

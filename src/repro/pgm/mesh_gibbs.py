@@ -20,9 +20,7 @@ neighbours' pairwise sums via the validity mask that `pad_mrf` /
 `shard_mrf` produce (see `pad_mrf` for why the mask is load-bearing).
 
 Devices: this module is mesh-agnostic; tests exercise it in a subprocess
-with `--xla_force_host_platform_device_count`.  `shard_map` is resolved
-from `jax.shard_map` with a fallback to `jax.experimental.shard_map` so
-the module also runs on older jax (0.4.x) installs.
+with `--xla_force_host_platform_device_count`.
 """
 from __future__ import annotations
 
@@ -38,14 +36,6 @@ from repro.core.interp import exp_table
 from repro.core.ky import ky_sample
 from repro.kernels.fused_sweep import fused_gibbs_sample
 from repro.pgm.graph import MRFGrid
-
-try:
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW: dict = {}
-except AttributeError:  # pragma: no cover - jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-    # the old replication checker has no rule for while_loop (ky_sample)
-    _SHARD_MAP_KW = {"check_rep": False}
 
 _EXP = exp_table()
 
@@ -86,7 +76,7 @@ def _halo_exchange(tile: jax.Array, row_axis: str, col_axis: str,
     """Collect N/S/E/W one-site halos of a (B, ht, wt) int32 tile.
 
     ``nr``/``nc`` are the static mesh axis sizes (the ppermute pairs need
-    concrete indices; ``jax.lax.axis_size`` is also absent on jax 0.4.x).
+    concrete indices).
     Returns the padded (B, ht+2, wt+2) labels; which halo entries are
     *meaningful* is the caller's precomputed validity mask's business
     (:func:`blocked_validity` covers both the global boundary and pad
@@ -224,12 +214,11 @@ def make_mesh_gibbs_step(
                 P(row_axis, col_axis, None), P(), P(row_axis, col_axis))
     if clamped:
         in_specs = in_specs + (P(row_axis, col_axis),)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(None, row_axis, col_axis), P(row_axis, col_axis)),
-        **_SHARD_MAP_KW,
     )
     return jax.jit(mapped)
 

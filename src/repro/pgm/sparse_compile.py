@@ -290,6 +290,7 @@ def _sparse_color_update(
     use_iu: bool,
     sampler: str = "xla",
     beta: jax.Array | None = None,   # traced inverse temperature, (B,) or scalar
+    mesh=None,                       # serve mesh the lane axis is sharded on
 ) -> tuple[jax.Array, BNSweepStats]:
     """Resample every node of one color, all lanes at once.
 
@@ -314,7 +315,7 @@ def _sparse_color_update(
             card[nodes][None], energies.shape[:-1]).reshape(-1)
         res = fused_gibbs_sample(
             key, (-energies).reshape((-1, max_card)), lane_card,
-            k=k, use_iu=use_iu)
+            k=k, use_iu=use_iu, mesh=mesh)
     else:
         wts = ky_weights(-energies, card[nodes], k, use_iu)
         res = ky_sample(key, wts.reshape((-1, max_card)))

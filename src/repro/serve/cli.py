@@ -800,8 +800,10 @@ def main(argv=None) -> None:
     if args.force_host_devices:
         from repro.launch.mesh import force_host_devices
         force_host_devices(args.force_host_devices)
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve.engine import PosteriorEngine
 
+    enable_compile_cache()
     mesh = None
     if args.mesh_shape:
         import jax

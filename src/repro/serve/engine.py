@@ -371,11 +371,18 @@ class GroupRun:
                 # assignment-stability retirement: the annealed chains'
                 # per-round argmax must sit still for map_stable_rounds
                 # consecutive rounds (rd can be all-zero when thin > spr
-                # leaves a round with no kept draw — skip those rounds)
+                # leaves a round with no kept draw — skip those rounds).
+                # Only rounds at the schedule's final beta count: a warm
+                # round's per-variable vote argmax is a marginal mode,
+                # which need not be the joint MAP
+                t = s.anneal_rounds - 1
+                cold = eng.map_beta(t) >= eng.map_beta(t + 1)
                 if rd.any():
                     assign = np.where(
                         self._card_mask, rd, -1).argmax(axis=1)
-                    if (s.map_last is not None
+                    if not cold:
+                        s.map_stable = 0
+                    elif (s.map_last is not None
                             and np.array_equal(assign, s.map_last)):
                         s.map_stable += 1
                     else:
@@ -878,6 +885,13 @@ class PosteriorEngine:
         model = self._network(query.network)
         ev, qvars, pattern = family_of(model).normalize(model, query)
         return model, ev, qvars, pattern
+
+    def round_runner(self, query: Request):
+        """The jitted round runner that serves ``query``'s plan (taken
+        from, or put into, the plan cache) — for ahead-of-time
+        ``lower(...).compile()`` of the exact program the engine runs."""
+        _, _, _, pattern = self.normalize(query)
+        return self._plan(query.network, pattern)[1]
 
     def answer(self, query: Request) -> Result:
         return self.answer_batch([query])[0]

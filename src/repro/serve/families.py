@@ -105,7 +105,7 @@ def make_round_runner(prog, *, sweeps_per_round: int, thin: int,
                 sub, s2 = jax.random.split(sub)
                 x, st = _color_update(
                     s2, x, plan, log_cpt, L, prog.k, use_iu, sampler,
-                    beta)
+                    beta, mesh)
                 bits, att = bits + st.bits_used, att + st.attempts
             onehot = (x[..., None] == jnp.arange(L)).astype(jnp.int32)
             kept = ((offset + i) % thin) == 0
@@ -173,10 +173,12 @@ def make_mrf_round_runner(prog: CompiledMRF, *, sweeps_per_round: int,
             key, k0, k1 = jax.random.split(key, 3)
             x, s0 = checkerboard_halfstep(
                 k0, x, unary, pairwise, jnp.int32(0), clamp=clamp,
-                k=prog.k, use_iu=use_iu, sampler=sampler, beta=beta)
+                k=prog.k, use_iu=use_iu, sampler=sampler, beta=beta,
+                mesh=mesh)
             x, s1 = checkerboard_halfstep(
                 k1, x, unary, pairwise, jnp.int32(1), clamp=clamp,
-                k=prog.k, use_iu=use_iu, sampler=sampler, beta=beta)
+                k=prog.k, use_iu=use_iu, sampler=sampler, beta=beta,
+                mesh=mesh)
             flat = x.reshape(b, h * w)
             onehot = (flat[..., None] == jnp.arange(L)).astype(jnp.int32)
             kept = ((offset + i) % thin) == 0
@@ -244,7 +246,7 @@ def make_fg_round_runner(prog: CompiledFactorGraph, *,
                 sub, s2 = jax.random.split(sub)
                 x, st = _sparse_color_update(
                     s2, x, plan, unary, tables_flat, card, L, prog.k,
-                    use_iu, sampler, beta)
+                    use_iu, sampler, beta, mesh)
                 bits, att = bits + st.bits_used, att + st.attempts
             onehot = (x[..., None] == jnp.arange(L)).astype(jnp.int32)
             kept = ((offset + i) % thin) == 0

@@ -262,12 +262,17 @@ class TestAsiaOrGateRegression:
     """The ROADMAP failure mode: asia's near-deterministic OR gate.
 
     Conditioned on dysp=1, `tub` (an input of `either = tub OR lung`)
-    is a rare event whose flips are coupled to the gate.  With 16
-    chains the round means agree early, so legacy split-R̂ retires at
-    the very first check with a biased marginal; the rank+ESS rule
-    keeps sampling until the min-ESS gate passes and lands within
-    tolerance of the exact answer.  Same configuration as the worked
-    example in docs/diagnostics.md.
+    is a rare event whose flips are coupled to the gate.  Legacy
+    split-R̂ looks only at agreement between round means, so it may
+    retire before the chains hold enough independent draws; the
+    rank+ESS rule keeps sampling until the min-ESS gate passes.  Same
+    configuration as the worked example in docs/diagnostics.md.
+
+    How early legacy stops, and how biased its answer is, varies from
+    seed to seed, so the claims are made over several seeds: both rules
+    see the same chains, legacy never outlasts rank and stops sooner in
+    total, every rank answer passes its ESS gate and lands within
+    tolerance of the exact answer, and legacy's mean error is larger.
     """
 
     def test_legacy_retires_early_and_biased_rank_keeps_sampling(self):
@@ -275,30 +280,32 @@ class TestAsiaOrGateRegression:
         from repro.serve import PosteriorEngine, Query
 
         q = Query("asia", {"dysp": 1}, ("tub",), n_samples=10 ** 6)
-        kw = dict(chains_per_query=16, burn_in=16, sweeps_per_round=16,
-                  max_rounds=48, seed=0)
-        legacy = PosteriorEngine({"asia": networks.asia()},
-                                 retirement="legacy", **kw).answer(q)
-        rank = PosteriorEngine({"asia": networks.asia()},
-                               retirement="rank", **kw).answer(q)
-
-        # legacy stopped well before rank did...
-        assert legacy.converged
-        assert legacy.n_sweeps < rank.n_sweeps
-        # ...with an ESS far below the default target
-        assert legacy.diagnostics.min_ess < 100
-        # rank kept sampling until the ESS gate passed
-        assert rank.converged
-        assert rank.diagnostics.min_ess >= 100
-
         exact = networks.asia().marginals_exact({"dysp": 1})
         idx = networks.asia().index("tub")
-        err_legacy = float(abs(legacy.marginal("tub") - exact[idx]).max())
-        err_rank = float(abs(rank.marginal("tub") - exact[idx]).max())
-        # the early retirement kept its bias; the rank answer is exact
-        # to tolerance and strictly better
-        assert err_rank < 0.02 < err_legacy
-        assert err_rank < err_legacy
+        runs = []
+        for seed in range(4):
+            kw = dict(chains_per_query=16, burn_in=16, sweeps_per_round=16,
+                      max_rounds=48, seed=seed)
+            legacy = PosteriorEngine({"asia": networks.asia()},
+                                     retirement="legacy", **kw).answer(q)
+            rank = PosteriorEngine({"asia": networks.asia()},
+                                   retirement="rank", **kw).answer(q)
+            runs.append((legacy, rank))
+
+        def err(res):
+            return float(abs(res.marginal("tub") - exact[idx]).max())
+
+        assert all(lg.converged and rk.converged for lg, rk in runs)
+        # legacy never stops later than rank, and stops sooner overall
+        assert all(lg.n_sweeps <= rk.n_sweeps for lg, rk in runs)
+        assert (sum(lg.n_sweeps for lg, _ in runs)
+                < sum(rk.n_sweeps for _, rk in runs))
+        # rank kept sampling until the ESS gate passed, and is accurate
+        assert all(rk.diagnostics.min_ess >= 100 for _, rk in runs)
+        assert all(err(rk) < 0.02 for _, rk in runs)
+        # the earlier stops cost accuracy on average
+        assert (np.mean([err(lg) for lg, _ in runs])
+                > np.mean([err(rk) for _, rk in runs]))
 
 
 class TestRegressionGateModes:

@@ -6,20 +6,11 @@ bytes, MRF pad-site masking on non-tile-multiple grids, the sharded
 posterior query service, sharded train-step parity with single-device,
 dry-run builders on a small mesh, checkpoint restore-with-reshard
 (elastic restart).
-
-The PGM/serve mesh layers run on any jax with shard_map/NamedSharding;
-the training meshes target the explicit-sharding API (AxisType,
-jax.set_mesh) and are gated on jax >= 0.6.
 """
 
-import jax
 import pytest
 
 from conftest import run_subprocess
-
-requires_explicit_mesh = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="needs jax.sharding.AxisType / explicit-mesh API (jax >= 0.6)")
 
 
 @pytest.mark.slow
@@ -190,6 +181,36 @@ print("OK")
         rc, out = run_subprocess(code, devices=4)
         assert rc == 0, out
 
+    def test_fused_sampler_on_mesh_matches_single_device(self):
+        """sampler="pallas" on a 4-device serve mesh runs the fused
+        kernel per device on its own lanes (XLA cannot partition a
+        Mosaic kernel); the answers equal the single-device engine's, and
+        the xla sampler's, bit for bit — BN and MRF families."""
+        code = """
+import numpy as np
+from repro.launch.mesh import make_serve_mesh
+from repro.pgm import networks
+from repro.serve import MrfQuery, PosteriorEngine, Query
+mrf, truth = networks.penguin_task(h=6, w=5)
+registry = {"sprinkler": networks.sprinkler(), "penguin": mrf}
+mask = np.zeros((6, 5), bool); mask[2, 1:4] = True
+qs = [Query("sprinkler", {"wetgrass": 1}, ("rain",), n_samples=512),
+      MrfQuery("penguin", mask, truth, query_sites=((0, 0), (5, 4)),
+               n_samples=256)]
+kw = dict(chains_per_query=4, burn_in=16, max_rounds=6, seed=1)
+runs = [PosteriorEngine(registry, sampler="pallas",
+                        mesh=make_serve_mesh((4,)), **kw).answer_batch(qs),
+        PosteriorEngine(registry, sampler="pallas", **kw).answer_batch(qs),
+        PosteriorEngine(registry, sampler="xla", **kw).answer_batch(qs)]
+for rs in zip(*runs):
+    for var in rs[0].marginals:
+        for r in rs[1:]:
+            assert np.array_equal(rs[0].marginal(var), r.marginal(var))
+print("OK")
+"""
+        rc, out = run_subprocess(code, devices=4)
+        assert rc == 0, out
+
     def test_mesh_shape_2d_and_cli(self):
         """2D ("batch", "model") serve mesh + the CLI flags end to end."""
         code = """
@@ -204,7 +225,6 @@ main(["--network", "sprinkler", "--queries", "4", "--patterns", "2",
 
 
 @pytest.mark.slow
-@requires_explicit_mesh
 class TestShardedTraining:
     def test_sharded_step_matches_single_device(self):
         code = """
@@ -284,7 +304,6 @@ print("OK")
 
 
 @pytest.mark.slow
-@requires_explicit_mesh
 class TestDryrunSmall:
     def test_builders_compile_on_small_mesh(self):
         """The cell builders lower+compile on a 2x2 mesh for one arch of

@@ -80,21 +80,27 @@ class TestAnnealedMap:
     @pytest.mark.parametrize("sampler", ["xla", "pallas"])
     def test_frustrated_triangle_ground_state(self, sampler):
         """MAP on a frustrated Ising triangle (spin 0 clamped up) finds
-        the enumeration ground state of the conditioned model."""
+        a ground state of the conditioned model.  Frustration makes the
+        ground state degenerate, so the check is on energy: the reported
+        energy is the enumerated minimum, and the reported assignment is
+        one of the states that attain it."""
         model = _frustrated_triangle()
         fg = model.to_factor_graph()
         grids = np.indices((2, 2, 2)).reshape(3, -1).T
         grids = grids[grids[:, 0] == 1]          # clamp s0 = +1
-        best = grids[np.argmin(fg.energy(grids))]
+        energies = fg.energy(grids)
+        e_min = float(energies.min())
+        ground = {(int(g[1]), int(g[2])) for g, e in zip(grids, energies)
+                  if e == pytest.approx(e_min, abs=1e-6)}
 
         eng = PosteriorEngine({"tri": model}, chains_per_query=8,
                               burn_in=16, sampler=sampler, seed=0)
         res = eng.answer(IsingQuery("tri", clamp_sites=((0, +1),),
                                     query_vars=(1, 2), mode="map",
                                     n_samples=2048))
-        assert res.map_assignment == {"s1": int(best[1]), "s2": int(best[2])}
-        assert res.map_energy == pytest.approx(float(fg.energy(best)),
-                                               abs=1e-4)
+        assert res.map_energy == pytest.approx(e_min, abs=1e-4)
+        got = (res.map_assignment["s1"], res.map_assignment["s2"])
+        assert got in ground
 
     def test_marginal_raises_on_map_result(self):
         eng = PosteriorEngine(_registry(), chains_per_query=8, burn_in=16,
