@@ -238,8 +238,36 @@ def _color_update(
     beta: jax.Array | None = None,   # traced inverse temperature, (B,) or scalar
     mesh=None,                       # serve mesh the lane axis is sharded on
 ) -> tuple[jax.Array, BNSweepStats]:
-    ls = jnp.arange(max_card, dtype=jnp.int32)            # (L,)
+    with jax.named_scope("weights"):
+        logw, card = _color_logw(x, plan, log_cpt, max_card, beta)
     nodes = jnp.asarray(plan.nodes)
+
+    # --- IU-exp → fixed point → KY sample ---------------------------------
+    # sampler="pallas": mask → LUT-exp → floor → KY walk fused in one
+    # Pallas kernel, weight tile resident in VMEM (kernels/fused_sweep.py);
+    # bitwise-identical to the two-stage XLA path below by construction.
+    if sampler == "pallas":
+        lane_card = jnp.broadcast_to(
+            card[None], logw.shape[:-1]).reshape(-1)
+        with jax.named_scope("ky_walk"):
+            res = fused_gibbs_sample(
+                key, logw.reshape((-1, max_card)), lane_card,
+                k=k, use_iu=use_iu, table=_EXP, mesh=mesh)
+    else:
+        with jax.named_scope("weights"):
+            wts = ky_weights(logw, card, k, use_iu)
+        with jax.named_scope("ky_walk"):
+            res = ky_sample(key, wts.reshape((-1, max_card)))
+    new = res.sample.reshape(logw.shape[:-1]).astype(jnp.int32)  # (B, G)
+    x = x.at[:, nodes].set(new)
+    return x, BNSweepStats(jnp.sum(res.bits_used), jnp.sum(res.attempts))
+
+
+def _color_logw(x, plan, log_cpt, max_card, beta):
+    """(B, G, L) log-weights of one color's nodes given states ``x`` (own
+    CPT row plus the children's likelihood terms, β-scaled), and the
+    nodes' cardinalities (G,)."""
+    ls = jnp.arange(max_card, dtype=jnp.int32)            # (L,)
     card = jnp.asarray(plan.card)                          # (G,)
 
     # --- own CPT row: offset + Σ stride_j * x[pa_j] + l -------------------
@@ -274,23 +302,7 @@ def _color_update(
         valid = ls[None, None, :] < card[None, :, None]
         m = jnp.max(jnp.where(valid, logw, -jnp.inf), axis=-1, keepdims=True)
         logw = (logw - m) * b
-
-    # --- IU-exp → fixed point → KY sample ---------------------------------
-    # sampler="pallas": mask → LUT-exp → floor → KY walk fused in one
-    # Pallas kernel, weight tile resident in VMEM (kernels/fused_sweep.py);
-    # bitwise-identical to the two-stage XLA path below by construction.
-    if sampler == "pallas":
-        lane_card = jnp.broadcast_to(
-            card[None], logw.shape[:-1]).reshape(-1)
-        res = fused_gibbs_sample(
-            key, logw.reshape((-1, max_card)), lane_card,
-            k=k, use_iu=use_iu, table=_EXP, mesh=mesh)
-    else:
-        wts = ky_weights(logw, card, k, use_iu)
-        res = ky_sample(key, wts.reshape((-1, max_card)))
-    new = res.sample.reshape(logw.shape[:-1]).astype(jnp.int32)  # (B, G)
-    x = x.at[:, nodes].set(new)
-    return x, BNSweepStats(jnp.sum(res.bits_used), jnp.sum(res.attempts))
+    return logw, card
 
 
 def make_sweep(prog: CompiledBN, *, use_iu: bool = True,

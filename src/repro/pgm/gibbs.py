@@ -122,24 +122,32 @@ def checkerboard_halfstep(
     """
     b, h, w = labels.shape
     l = unary.shape[-1]
-    if beta is None:
-        energies = None  # keep the β-free trace byte-identical to the old one
-    else:
-        energies = unary[None] + neighbor_pair_energy(labels, pairwise)
-        bb = jnp.asarray(beta, energies.dtype)
-        energies = energies * (bb[:, None, None, None] if bb.ndim == 1 else bb)
-    if sampler == "pallas":
-        if energies is None:
+    # named scopes: the weight path (energies and, under xla, the IU-exp
+    # table) and the KY walk (under pallas, the fused kernel with its
+    # table) are found by name in a profile's op metadata
+    with jax.named_scope("weights"):
+        if beta is None:
+            energies = None  # keep the β-free trace byte-identical
+        else:
             energies = unary[None] + neighbor_pair_energy(labels, pairwise)
-        res = fused_gibbs_sample(
-            key, (-energies).reshape((-1, l)), l, k=k, use_iu=use_iu,
-            table=_EXP, mesh=mesh)
-    else:
-        if energies is None:
+            bb = jnp.asarray(beta, energies.dtype)
+            energies = energies * (
+                bb[:, None, None, None] if bb.ndim == 1 else bb)
+        if sampler == "pallas":
+            if energies is None:
+                energies = unary[None] + neighbor_pair_energy(
+                    labels, pairwise)
+            logw = (-energies).reshape((-1, l))
+        elif energies is None:
             wts = site_weights(labels, unary, pairwise, k=k, use_iu=use_iu)
         else:
             wts = _weights_from_energies(energies, k=k, use_iu=use_iu)
-        res = ky_sample(key, wts.reshape((-1, l)))
+    with jax.named_scope("ky_walk"):
+        if sampler == "pallas":
+            res = fused_gibbs_sample(key, logw, l, k=k, use_iu=use_iu,
+                                     table=_EXP, mesh=mesh)
+        else:
+            res = ky_sample(key, wts.reshape((-1, l)))
     new = res.sample.reshape((b, h, w))
     mask = (((jnp.arange(h)[:, None] + jnp.arange(w)[None, :]) % 2) == parity)[None]
     if clamp is not None:

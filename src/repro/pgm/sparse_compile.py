@@ -306,19 +306,23 @@ def _sparse_color_update(
     bitwise-identical to the XLA path by construction.
     """
     nodes = jnp.asarray(plan.nodes)
-    energies = _plan_energies(x, plan, unary, tables_flat, max_card)
-    if beta is not None:
-        bb = jnp.asarray(beta, energies.dtype)
-        energies = energies * (bb[:, None, None] if bb.ndim == 1 else bb)
+    with jax.named_scope("weights"):
+        energies = _plan_energies(x, plan, unary, tables_flat, max_card)
+        if beta is not None:
+            bb = jnp.asarray(beta, energies.dtype)
+            energies = energies * (bb[:, None, None] if bb.ndim == 1 else bb)
     if sampler == "pallas":
         lane_card = jnp.broadcast_to(
             card[nodes][None], energies.shape[:-1]).reshape(-1)
-        res = fused_gibbs_sample(
-            key, (-energies).reshape((-1, max_card)), lane_card,
-            k=k, use_iu=use_iu, mesh=mesh)
+        with jax.named_scope("ky_walk"):
+            res = fused_gibbs_sample(
+                key, (-energies).reshape((-1, max_card)), lane_card,
+                k=k, use_iu=use_iu, mesh=mesh)
     else:
-        wts = ky_weights(-energies, card[nodes], k, use_iu)
-        res = ky_sample(key, wts.reshape((-1, max_card)))
+        with jax.named_scope("weights"):
+            wts = ky_weights(-energies, card[nodes], k, use_iu)
+        with jax.named_scope("ky_walk"):
+            res = ky_sample(key, wts.reshape((-1, max_card)))
     new = res.sample.reshape(energies.shape[:-1]).astype(jnp.int32)
     x = x.at[:, nodes].set(new)
     return x, BNSweepStats(jnp.sum(res.bits_used), jnp.sum(res.attempts))

@@ -320,99 +320,61 @@ class GroupRun:
         retired this round (their ``result`` is filled in, or left None
         if cancelled)."""
         eng = self.engine
-        tel = self.tel
+        tel, tid = self.tel, self.tel_tid
         t_round0 = monotonic()
         busy = sum(not s.done for s in self.slots)
-        offsets = np.zeros(self.bt, np.int32)
-        for s in self.slots:
-            if not s.done and not s.burn_left:
-                offsets[s.j * self.c:(s.j + 1) * self.c] = s.rounds * self.spr
-        self._run_key, sub = jax.random.split(self._run_key)
-        if self.mode == "map":
-            # per-lane annealed inverse temperature: each slot walks the
-            # geometric schedule from its own admission round (backfilled
-            # slots restart at beta0), so one traced runner serves every
-            # point of every lane's schedule without retracing
-            betas = np.ones(self.bt, np.float32)
+        # child spans of ``round``, which they tile: dispatch, the wait
+        # for the round program, the read of its counts, and judging
+        # (with one ``retire`` per retired query inside it)
+        with tel.span("dispatch", tid):
+            offsets = np.zeros(self.bt, np.int32)
             for s in self.slots:
-                if not s.done:
-                    betas[s.j * self.c:(s.j + 1) * self.c] = eng.map_beta(
-                        s.anneal_rounds)
-                    s.anneal_rounds += 1
-            self.x, rc, xmean, xsq, st = self.runner(
-                sub, self.x, jnp.asarray(offsets), jnp.asarray(betas))
-        else:
-            # marginal groups keep the 3-arg call: beta=None traces the
-            # exact pre-annealing program (bitwise-pinned baselines)
-            self.x, rc, xmean, xsq, st = self.runner(
-                sub, self.x, jnp.asarray(offsets))
-        self.bits += int(sum_sweep_stats(st).bits_used)
+                if not s.done and not s.burn_left:
+                    offsets[s.j * self.c:(s.j + 1) * self.c] = \
+                        s.rounds * self.spr
+            self._run_key, sub = jax.random.split(self._run_key)
+            if self.mode == "map":
+                # per-lane annealed inverse temperature: each slot walks
+                # the geometric schedule from its own admission round
+                # (backfilled slots restart at beta0), so one traced
+                # runner serves every point of every lane's schedule
+                # without retracing
+                betas = np.ones(self.bt, np.float32)
+                for s in self.slots:
+                    if not s.done:
+                        betas[s.j * self.c:(s.j + 1) * self.c] = \
+                            eng.map_beta(s.anneal_rounds)
+                        s.anneal_rounds += 1
+                self.x, rc, xmean, xsq, st = self.runner(
+                    sub, self.x, jnp.asarray(offsets), jnp.asarray(betas))
+            else:
+                # marginal groups keep the 3-arg call: beta=None traces
+                # the exact pre-annealing program (bitwise-pinned
+                # baselines)
+                self.x, rc, xmean, xsq, st = self.runner(
+                    sub, self.x, jnp.asarray(offsets))
+        with tel.span("device_wait", tid):
+            bits = int(sum_sweep_stats(st).bits_used)
+        self.bits += bits
         self.sweeps_done += self.spr
 
-        rc_np = xmean_np = xsq_np = None  # host transfer only if needed
-        retired: list[GroupEntry] = []
-        for s in self.slots:
-            if s.done:
-                continue
-            if s.burn_left:
-                s.burn_left -= 1
-                continue
-            if rc_np is None:
+        # host transfer only if some slot counts this round
+        rc_np = xmean_np = xsq_np = None
+        if any(not s.done and not s.burn_left for s in self.slots):
+            with tel.span("readback", tid):
                 rc_np = np.asarray(rc, np.int64)
                 xmean_np = np.asarray(xmean)
                 xsq_np = np.asarray(xsq)
-            sl = slice(s.j * self.c, (s.j + 1) * self.c)
-            rd = rc_np[sl].sum(axis=0)        # this round's counts (n, L)
-            s.counts += rd
-            for v, d in s.diags.items():
-                d.update(xmean_np[sl, v], xsq_np[sl, v])
-            s.rounds += 1
-            if s.mode == "map":
-                # assignment-stability retirement: the annealed chains'
-                # per-round argmax must sit still for map_stable_rounds
-                # consecutive rounds (rd can be all-zero when thin > spr
-                # leaves a round with no kept draw — skip those rounds).
-                # Only rounds at the schedule's final beta count: a warm
-                # round's per-variable vote argmax is a marginal mode,
-                # which need not be the joint MAP
-                t = s.anneal_rounds - 1
-                cold = eng.map_beta(t) >= eng.map_beta(t + 1)
-                if rd.any():
-                    assign = np.where(
-                        self._card_mask, rd, -1).argmax(axis=1)
-                    if not cold:
-                        s.map_stable = 0
-                    elif (s.map_last is not None
-                            and np.array_equal(assign, s.map_last)):
-                        s.map_stable += 1
-                    else:
-                        s.map_stable = 1
-                    s.map_last = assign
-                if s.rounds >= eng.min_rounds:
-                    s.converged = s.map_stable >= eng.map_stable_rounds
-            elif s.rounds >= eng.min_rounds:
-                if eng.retirement == "rank":
-                    # staged check: the cheap R̂ gate first, the
-                    # O(rounds²) ESS estimators only once every
-                    # variable's R̂ passes — slow-mixing rounds never
-                    # pay for ESS they can't use (both all()s
-                    # short-circuit on the first failing variable)
-                    s.converged = all(
-                        d.rank_gate() < s.rhat_target
-                        for d in s.diags.values()) and all(
-                        d.compute().min_ess >= s.ess_target
-                        for d in s.diags.values())
-                else:  # legacy: plain split-R̂ over round means only
-                    s.rhat = max(
-                        d.legacy_rhat() for d in s.diags.values())
-                    s.converged = s.rhat < s.rhat_target
-            if s.converged or s.rounds >= s.cap:
-                reason = ("max-sweeps" if not s.converged
-                          else "map-stable" if s.mode == "map"
-                          else "rhat+ess" if eng.retirement == "rank"
-                          else "rhat")
-                self._retire(s, reason)
-                retired.append(s.entry)
+        retired: list[GroupEntry] = []
+        with tel.span("judge", tid):
+            for s in self.slots:
+                if s.done:
+                    continue
+                if s.burn_left:
+                    s.burn_left -= 1
+                    continue
+                if self._judge(s, rc_np, xmean_np, xsq_np):
+                    retired.append(s.entry)
         if tel.enabled:
             t_round1 = monotonic()
             # ESS trajectory, read for free: only slots whose retirement
@@ -427,21 +389,90 @@ class GroupRun:
                     ess[f"slot{s.j}"] = round(
                         min(d.min_ess for d in ds), 1)
             now_busy = sum(not s.done for s in self.slots)
+            # every lane updates every free site each sweep, vacant and
+            # pad lanes included: the denominator of bits_per_sample
+            updates = self.bt * self.n_free * self.spr
             tel.complete(
-                "round", self.tel_tid, t_round0, t_round1,
+                "round", tid, t_round0, t_round1,
                 sweeps=self.spr, lanes_busy=busy * self.c,
                 lanes_vacant=(len(self.slots) - busy) * self.c,
-                retired=len(retired), **({"ess": ess} if ess else {}))
+                retired=len(retired), site_updates=updates, bits=bits,
+                **({"ess": ess} if ess else {}))
             tel.sample("lanes_busy", now_busy * self.c)
             tel.count("serve_rounds_total", help="scheduling rounds run")
             tel.count("serve_sweeps_total", self.spr,
                       help="Gibbs sweeps run (all groups, incl. burn-in)")
+            tel.count("serve_site_updates_total", updates,
+                      help="site updates run, every lane (incl. burn-in)")
+            tel.count("serve_random_bits_total", bits,
+                      help="random bits the Knuth-Yao walks consumed")
             tel.gauge_set("serve_lanes_busy", now_busy * self.c,
                           help="chain lanes owned by live queries")
             tel.gauge_set(
                 "serve_lanes_vacant", (len(self.slots) - now_busy) * self.c,
                 help="padded/retired lanes available for backfill")
         return retired
+
+    def _judge(self, s: _Slot, rc_np: np.ndarray, xmean_np: np.ndarray,
+               xsq_np: np.ndarray) -> bool:
+        """Fold one round's counts and moments into a counting slot and
+        apply its retirement rule; True if the slot retired."""
+        eng = self.engine
+        sl = slice(s.j * self.c, (s.j + 1) * self.c)
+        rd = rc_np[sl].sum(axis=0)        # this round's counts (n, L)
+        s.counts += rd
+        for v, d in s.diags.items():
+            d.update(xmean_np[sl, v], xsq_np[sl, v])
+        s.rounds += 1
+        if s.mode == "map":
+            # assignment-stability retirement: the annealed chains'
+            # per-round argmax must sit still for map_stable_rounds
+            # consecutive rounds (rd can be all-zero when thin > spr
+            # leaves a round with no kept draw — skip those rounds).
+            # Only rounds at the schedule's final beta count: a warm
+            # round's per-variable vote argmax is a marginal mode,
+            # which need not be the joint MAP
+            t = s.anneal_rounds - 1
+            cold = eng.map_beta(t) >= eng.map_beta(t + 1)
+            if rd.any():
+                assign = np.where(
+                    self._card_mask, rd, -1).argmax(axis=1)
+                if not cold:
+                    s.map_stable = 0
+                elif (s.map_last is not None
+                        and np.array_equal(assign, s.map_last)):
+                    s.map_stable += 1
+                else:
+                    s.map_stable = 1
+                s.map_last = assign
+            if s.rounds >= eng.min_rounds:
+                s.converged = s.map_stable >= eng.map_stable_rounds
+        elif s.rounds >= eng.min_rounds:
+            if eng.retirement == "rank":
+                # staged check: the cheap R̂ gate first, the
+                # O(rounds²) ESS estimators only once every
+                # variable's R̂ passes — slow-mixing rounds never
+                # pay for ESS they can't use (both all()s
+                # short-circuit on the first failing variable)
+                s.converged = all(
+                    d.rank_gate() < s.rhat_target
+                    for d in s.diags.values()) and all(
+                    d.compute().min_ess >= s.ess_target
+                    for d in s.diags.values())
+            else:  # legacy: plain split-R̂ over round means only
+                s.rhat = max(
+                    d.legacy_rhat() for d in s.diags.values())
+                s.converged = s.rhat < s.rhat_target
+        if s.converged or s.rounds >= s.cap:
+            reason = ("max-sweeps" if not s.converged
+                      else "map-stable" if s.mode == "map"
+                      else "rhat+ess" if eng.retirement == "rank"
+                      else "rhat")
+            with self.tel.span("retire", self.tel_tid, qid=s.entry.tel_tid,
+                               sites=len(s.entry.qvars)):
+                self._retire(s, reason)
+            return True
+        return False
 
     def run_to_completion(self) -> None:
         while self.active:
@@ -545,13 +576,13 @@ class GroupRun:
             t_submit = s.t0
         t_wait1 = s.t0 if s.backfilled else self._plan_span[0]
         tel.complete("query", tid, t_submit, now,
-                     network=self.name, reason=reason)
-        tel.complete("wait", tid, t_submit, t_wait1)
+                     network=self.name, reason=reason, qid=tid)
+        tel.complete("wait", tid, t_submit, t_wait1, qid=tid)
         if not s.backfilled:
             tel.complete("plan", tid, *self._plan_span,
-                         cache_hit=self.cache_hit)
+                         cache_hit=self.cache_hit, qid=tid)
         tel.complete("service", tid, s.t_service0, now,
-                     rounds=s.rounds, sweeps=self.sweeps_done)
+                     rounds=s.rounds, sweeps=self.sweeps_done, qid=tid)
         tel.instant("retired", tid, reason=reason, rounds=s.rounds)
         tel.count("serve_retired_total", help="queries retired, by reason",
                   reason=reason)

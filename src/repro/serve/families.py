@@ -107,21 +107,23 @@ def make_round_runner(prog, *, sweeps_per_round: int, thin: int,
                     s2, x, plan, log_cpt, L, prog.k, use_iu, sampler,
                     beta, mesh)
                 bits, att = bits + st.bits_used, att + st.attempts
-            onehot = (x[..., None] == jnp.arange(L)).astype(jnp.int32)
-            kept = ((offset + i) % thin) == 0
-            if kept.ndim:  # per-lane offsets: broadcast over (node, label)
-                kept = kept[:, None, None]
-            counts = counts + jnp.where(kept, onehot, 0)
-            xf = x.astype(jnp.float32)
-            xsum = xsum + xf
-            xsqsum = xsqsum + xf * xf
+            with jax.named_scope("counts"):
+                onehot = (x[..., None] == jnp.arange(L)).astype(jnp.int32)
+                kept = ((offset + i) % thin) == 0
+                if kept.ndim:  # per-lane offsets: broadcast over (node, label)
+                    kept = kept[:, None, None]
+                counts = counts + jnp.where(kept, onehot, 0)
+                xf = x.astype(jnp.float32)
+                xsum = xsum + xf
+                xsqsum = xsqsum + xf * xf
             return (key, x, counts, xsum, xsqsum), BNSweepStats(bits, att)
 
         counts0 = jnp.zeros(x.shape + (L,), jnp.int32)
         xsum0 = jnp.zeros(x.shape, jnp.float32)
-        (key, x, counts, xsum, xsqsum), per_sweep = jax.lax.scan(
-            body, (key, x, counts0, xsum0, xsum0),
-            jnp.arange(sweeps_per_round))
+        with jax.named_scope("round"):
+            (key, x, counts, xsum, xsqsum), per_sweep = jax.lax.scan(
+                body, (key, x, counts0, xsum0, xsum0),
+                jnp.arange(sweeps_per_round))
         if state_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, state_sharding)
         return (x, counts, xsum / sweeps_per_round,
@@ -179,23 +181,25 @@ def make_mrf_round_runner(prog: CompiledMRF, *, sweeps_per_round: int,
                 k1, x, unary, pairwise, jnp.int32(1), clamp=clamp,
                 k=prog.k, use_iu=use_iu, sampler=sampler, beta=beta,
                 mesh=mesh)
-            flat = x.reshape(b, h * w)
-            onehot = (flat[..., None] == jnp.arange(L)).astype(jnp.int32)
-            kept = ((offset + i) % thin) == 0
-            if kept.ndim:  # per-lane offsets: broadcast over (site, label)
-                kept = kept[:, None, None]
-            counts = counts + jnp.where(kept, onehot, 0)
-            ff = flat.astype(jnp.float32)
-            xsum = xsum + ff
-            xsqsum = xsqsum + ff * ff
+            with jax.named_scope("counts"):
+                flat = x.reshape(b, h * w)
+                onehot = (flat[..., None] == jnp.arange(L)).astype(jnp.int32)
+                kept = ((offset + i) % thin) == 0
+                if kept.ndim:  # per-lane offsets: broadcast over (site, label)
+                    kept = kept[:, None, None]
+                counts = counts + jnp.where(kept, onehot, 0)
+                ff = flat.astype(jnp.float32)
+                xsum = xsum + ff
+                xsqsum = xsqsum + ff * ff
             return (key, x, counts, xsum, xsqsum), SweepStats(
                 s0.bits_used + s1.bits_used, s0.attempts + s1.attempts)
 
         counts0 = jnp.zeros((b, h * w, L), jnp.int32)
         xsum0 = jnp.zeros((b, h * w), jnp.float32)
-        (key, x, counts, xsum, xsqsum), per_sweep = jax.lax.scan(
-            body, (key, x, counts0, xsum0, xsum0),
-            jnp.arange(sweeps_per_round))
+        with jax.named_scope("round"):
+            (key, x, counts, xsum, xsqsum), per_sweep = jax.lax.scan(
+                body, (key, x, counts0, xsum0, xsum0),
+                jnp.arange(sweeps_per_round))
         if state_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, state_sharding)
         return (x, counts, xsum / sweeps_per_round,
@@ -248,21 +252,23 @@ def make_fg_round_runner(prog: CompiledFactorGraph, *,
                     s2, x, plan, unary, tables_flat, card, L, prog.k,
                     use_iu, sampler, beta, mesh)
                 bits, att = bits + st.bits_used, att + st.attempts
-            onehot = (x[..., None] == jnp.arange(L)).astype(jnp.int32)
-            kept = ((offset + i) % thin) == 0
-            if kept.ndim:  # per-lane offsets: broadcast over (node, label)
-                kept = kept[:, None, None]
-            counts = counts + jnp.where(kept, onehot, 0)
-            xf = x.astype(jnp.float32)
-            xsum = xsum + xf
-            xsqsum = xsqsum + xf * xf
+            with jax.named_scope("counts"):
+                onehot = (x[..., None] == jnp.arange(L)).astype(jnp.int32)
+                kept = ((offset + i) % thin) == 0
+                if kept.ndim:  # per-lane offsets: broadcast over (node, label)
+                    kept = kept[:, None, None]
+                counts = counts + jnp.where(kept, onehot, 0)
+                xf = x.astype(jnp.float32)
+                xsum = xsum + xf
+                xsqsum = xsqsum + xf * xf
             return (key, x, counts, xsum, xsqsum), BNSweepStats(bits, att)
 
         counts0 = jnp.zeros(x.shape + (L,), jnp.int32)
         xsum0 = jnp.zeros(x.shape, jnp.float32)
-        (key, x, counts, xsum, xsqsum), per_sweep = jax.lax.scan(
-            body, (key, x, counts0, xsum0, xsum0),
-            jnp.arange(sweeps_per_round))
+        with jax.named_scope("round"):
+            (key, x, counts, xsum, xsqsum), per_sweep = jax.lax.scan(
+                body, (key, x, counts0, xsum0, xsum0),
+                jnp.arange(sweeps_per_round))
         if state_sharding is not None:
             x = jax.lax.with_sharding_constraint(x, state_sharding)
         return (x, counts, xsum / sweeps_per_round,

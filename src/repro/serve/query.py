@@ -288,6 +288,7 @@ class QueryHandle:
         self._on_cancel = on_cancel       # queue callback: pre-dispatch unlink
         self._callbacks: list = []        # run once, at terminal resolution
         self.cancel_requested = False     # dispatcher polls at round edges
+        self.qid = 0                      # telemetry track id (0 = untracked)
 
     @property
     def status(self) -> QueryStatus:

@@ -148,6 +148,9 @@ class AdmissionQueue:
         self.backfill = bool(backfill)
         self.stats = QueueStats()
         self.tel = engine.telemetry
+        # the dispatcher thread's own track: await_work, group_start,
+        # deliver and admit spans, between the groups' round spans
+        self.tel_tid = self.tel.track("dispatcher")
         engine._attached_queue = self  # PosteriorEngine.stats() snapshot
         self._buckets: dict[tuple, deque[GroupEntry]] = {}
         self._cv = threading.Condition()
@@ -169,7 +172,7 @@ class AdmissionQueue:
         entry = GroupEntry(query, ev, qvars, handle=handle)
         tel = self.tel
         if tel.enabled:
-            entry.tel_tid = tel.track(
+            entry.tel_tid = handle.qid = tel.track(
                 f"query#{next(self.engine._query_seq)} {query.network}")
         with self._cv:
             if self._closed:
@@ -478,8 +481,9 @@ class AdmissionQueue:
         return out
 
     def _run(self) -> None:
+        tel, tid = self.tel, self.tel_tid
         while True:
-            with self._cv:
+            with tel.span("await_work", tid), self._cv:
                 item = self._pop_ready_locked()
                 while item is None:
                     if self._closed and not self._buckets:
@@ -564,9 +568,27 @@ class AdmissionQueue:
             self._cv.notify_all()
         return True
 
+    def _backfill(self, key: tuple, run) -> None:
+        """Admit waiting queries of the running group's plan into its
+        freed slots (a stream's next slice waits for its predecessor)."""
+        busy_streams = set()
+        for s in run.slots:
+            if not s.done and s.entry is not None:
+                sid = getattr(s.entry.query, "stream_id", None)
+                if sid is not None:
+                    busy_streams.add(sid)
+        for e in self._take_pending(key, run.free_slots(), busy_streams):
+            with self._cv:
+                self._inflight.append(e)
+            e.handle._mark_running()
+            run.admit(e)
+            self.stats.backfilled += 1
+
     def _dispatch_run(self, key, name, pattern, batch) -> None:
+        tel, tid = self.tel, self.tel_tid
         try:
-            run = self._group_run(name, pattern, batch)
+            with tel.span("group_start", tid):
+                run = self._group_run(name, pattern, batch)
         except BaseException as exc:
             for e in batch:
                 e.handle._finish(QueryStatus.FAILED, error=exc)
@@ -592,31 +614,24 @@ class AdmissionQueue:
                     break
                 if self._preempt_run(key, run):
                     return
-                for e in run.step():
-                    # a cancel() that already promised "no result" wins
-                    # over the retirement (resolved atomically in _finish)
-                    final = e.handle._finish(QueryStatus.DONE, result=e.result)
-                    if final is QueryStatus.CANCELLED:
-                        self.stats.cancelled_in_flight += 1
-                        self._tel_done(e, "cancelled")
-                    elif final is not None:
-                        self.stats.completed += 1
-                        self._tel_done(e, "completed")
+                retired = run.step()
+                with tel.span("deliver", tid):
+                    for e in retired:
+                        # a cancel() that already promised "no result"
+                        # wins over the retirement (resolved atomically
+                        # in _finish)
+                        final = e.handle._finish(
+                            QueryStatus.DONE, result=e.result)
+                        if final is QueryStatus.CANCELLED:
+                            self.stats.cancelled_in_flight += 1
+                            self._tel_done(e, "cancelled")
+                        elif final is not None:
+                            self.stats.completed += 1
+                            self._tel_done(e, "completed")
                 if (self.backfill and run.active and run.free_slots()
                         and not self._other_bucket_ripe(key)):
-                    busy_streams = set()
-                    for s in run.slots:
-                        if not s.done and s.entry is not None:
-                            sid = getattr(s.entry.query, "stream_id", None)
-                            if sid is not None:
-                                busy_streams.add(sid)
-                    for e in self._take_pending(key, run.free_slots(),
-                                                busy_streams):
-                        with self._cv:
-                            self._inflight.append(e)
-                        e.handle._mark_running()
-                        run.admit(e)
-                        self.stats.backfilled += 1
+                    with tel.span("admit", tid):
+                        self._backfill(key, run)
         except BaseException as exc:
             for s in run.slots:
                 if s.entry is not None and not s.entry.handle.done():

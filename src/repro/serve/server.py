@@ -64,6 +64,7 @@ from repro.serve.protocol import (
     result_to_wire)
 from repro.serve.query import QueryStatus
 from repro.serve.sched import TokenBucket
+from repro.serve.telemetry import NULL, monotonic
 from repro.serve.worker import WorkerDied, WorkerPool
 
 __all__ = ["ServeFrontEnd", "start_in_thread"]
@@ -81,6 +82,32 @@ class _Shed(Exception):
         self.code = code
         self.reason = reason
         self.retry_after = retry_after
+
+
+class _Exchange:
+    """One request's trip through the front end, for its telemetry: the
+    ``request`` span runs from the body (or frame) in hand to the
+    response bytes written, on the query's own track (``qid``) of the
+    serving worker's recorder; both are set once the query is routed."""
+
+    __slots__ = ("transport", "t0", "tel", "qid")
+
+    def __init__(self, transport: str):
+        self.transport, self.t0 = transport, monotonic()
+        self.tel, self.qid = NULL, 0
+
+    def encode(self, code: int, body: dict, obj) -> bytes:
+        """The response body as JSON bytes; a WebSocket result frame
+        also carries the request's ``id`` and its ``status``."""
+        if self.transport == "ws":
+            if isinstance(obj, dict) and "id" in obj:
+                body.setdefault("id", obj["id"])
+            body.setdefault("status", code)
+        return json.dumps(body).encode()
+
+    def sent(self) -> None:
+        self.tel.complete("request", self.qid, self.t0, monotonic(),
+                          qid=self.qid, transport=self.transport)
 
 
 class ServeFrontEnd:
@@ -152,17 +179,24 @@ class ServeFrontEnd:
                     f"({self.quota_qps}/s)", retry_after=retry)
 
     # -- handle bridging ---------------------------------------------------
-    def _bridge(self, handle) -> asyncio.Future:
+    def _bridge(self, handle, tel=NULL) -> asyncio.Future:
         """A thread-side QueryHandle as an awaitable resolving to the
         handle itself once terminal (never raising — the caller reads
-        status/error off the handle)."""
+        status/error off the handle).  ``tel`` records the ``resolve``
+        span: from the done-callback on the dispatcher thread to the
+        future's resolution on the loop (the loop's and the GIL's
+        wait)."""
         loop = self._loop
         fut = loop.create_future()
 
         def done(h, fut=fut, loop=loop):
+            t_done = monotonic()
+
             def resolve():
                 if not fut.done():
                     fut.set_result(h)
+                tel.complete("resolve", h.qid, t_done, monotonic(),
+                             qid=h.qid)
             try:
                 loop.call_soon_threadsafe(resolve)
             except RuntimeError:
@@ -170,13 +204,14 @@ class ServeFrontEnd:
         handle.add_done_callback(done)
         return fut
 
-    async def _run_query(self, query):
+    async def _run_query(self, query, ex: _Exchange):
         """Route, submit, await; resubmits across workers while the
         failure says it is safe to.  Returns the terminal handle."""
         exclude: set[str] = set()
         while True:
             worker, handle = self.pool.submit(query, exclude=exclude)
-            h = await self._bridge(handle)
+            ex.tel, ex.qid = worker.engine.telemetry, handle.qid
+            h = await self._bridge(handle, ex.tel)
             err = h._error
             if (h.status is QueryStatus.FAILED
                     and isinstance(err, WorkerDied) and err.resubmit
@@ -197,20 +232,23 @@ class ServeFrontEnd:
             body["id"] = rid
         return 500, body
 
-    async def _serve_one(self, obj) -> tuple[int, dict, dict]:
+    async def _serve_one(self, obj, ex: _Exchange) -> tuple[int, bytes, dict]:
+        """One wire query: parse, admit, route, await, encode.  Returns
+        the status, the body as JSON bytes (``_Exchange.encode``) and
+        any extra headers."""
         try:
             query, rid = parse_wire_request(obj)
             self._admit(query)
         except WireError as exc:
-            return exc.code, exc.body, {}
+            return exc.code, ex.encode(exc.code, exc.body, obj), {}
         except _Shed as exc:
-            return exc.code, {
+            return exc.code, ex.encode(exc.code, {
                 "error": str(exc), "v": WIRE_VERSION,
                 "retry_after_s": exc.retry_after,
-            }, {"Retry-After": f"{max(exc.retry_after, 0.001):.3f}"}
+            }, obj), {"Retry-After": f"{max(exc.retry_after, 0.001):.3f}"}
         self._pending += 1
         try:
-            h = await self._run_query(query)
+            h = await self._run_query(query, ex)
         except (KeyError, ValueError) as exc:
             # the wire schema can't know model internals: an unknown
             # network/node only surfaces when routing normalizes the
@@ -218,13 +256,15 @@ class ServeFrontEnd:
             body = error_body(exc)
             if rid is not None:
                 body["id"] = rid
-            return 400, body, {}
+            return 400, ex.encode(400, body, obj), {}
         finally:
             self._pending -= 1
-        code, body = self._handle_to_wire(h, rid)
+        with ex.tel.span("encode", ex.qid):
+            code, body = self._handle_to_wire(h, rid)
+            data = ex.encode(code, body, obj)
         if code == 200:
             self.served += 1
-        return code, body, {}
+        return code, data, {}
 
     async def _serve_batch(self, obj) -> tuple[int, dict, dict]:
         if (not isinstance(obj, dict) or obj.get("v") != WIRE_VERSION
@@ -317,10 +357,12 @@ class ServeFrontEnd:
                         break
                     body = await reader.readexactly(n)
                 keep = headers.get("connection", "").lower() != "close"
+                ex = _Exchange("http")
                 code, payload, extra = await self._route(
-                    method, path, body)
+                    method, path, body, ex)
                 await self._respond(writer, code, payload, extra,
                                     keep_alive=keep)
+                ex.sent()
                 if not keep:
                     break
         except (asyncio.IncompleteReadError, ConnectionError):
@@ -351,8 +393,8 @@ class ServeFrontEnd:
             raise ConnectionError("too many headers")
         return method, path.split("?", 1)[0], headers
 
-    async def _route(self, method: str, path: str,
-                     body: bytes) -> tuple[int, object, dict]:
+    async def _route(self, method: str, path: str, body: bytes,
+                     ex: _Exchange) -> tuple[int, object, dict]:
         if path == "/healthz":
             return self._healthz()
         if path == "/stats":
@@ -369,7 +411,7 @@ class ServeFrontEnd:
                          "v": WIRE_VERSION}, {}
         try:
             if path == "/v2/query":
-                return await self._serve_one(obj)
+                return await self._serve_one(obj, ex)
             if path == "/v2/batch":
                 return await self._serve_batch(obj)
             if path == "/v2/flush":
@@ -386,6 +428,8 @@ class ServeFrontEnd:
                        keep_alive: bool = True) -> None:
         if isinstance(payload, str):
             data, ctype = payload.encode(), "text/plain; version=0.0.4"
+        elif isinstance(payload, bytes):       # JSON, already encoded
+            data, ctype = payload, "application/json"
         else:
             data = json.dumps(payload).encode()
             ctype = "application/json"
@@ -422,33 +466,30 @@ class ServeFrontEnd:
             async with send_lock:
                 await self._ws_send(writer, json.dumps(obj).encode())
 
-        async def serve(obj) -> None:
+        async def serve(obj, ex: _Exchange) -> None:
             try:
-                code, body, extra = await self._serve_one(obj)
+                _, data, _ = await self._serve_one(obj, ex)
             except Exception as exc:
                 # a handler bug must still answer this frame's id —
                 # dropping it would hang the client's collect loop
-                code, body, extra = 500, error_body(exc), {}
-            if extra.get("Retry-After"):
-                body.setdefault("retry_after_s",
-                                float(extra["Retry-After"]))
-            if isinstance(obj, dict) and "id" in obj:
-                body.setdefault("id", obj["id"])
-            body.setdefault("status", code)
-            await send_json(body)
+                data = ex.encode(500, error_body(exc), obj)
+            async with send_lock:
+                await self._ws_send(writer, data)
+            ex.sent()
 
         try:
             while True:
                 frame = await self._ws_recv(reader)
                 if frame is None:          # close frame or EOF
                     break
+                ex = _Exchange("ws")
                 try:
                     obj = json.loads(frame.decode())
                 except (ValueError, UnicodeDecodeError):
                     await send_json({"error": "frame is not valid JSON",
                                      "v": WIRE_VERSION, "status": 400})
                     continue
-                t = asyncio.ensure_future(serve(obj))
+                t = asyncio.ensure_future(serve(obj, ex))
                 tasks.add(t)
                 t.add_done_callback(tasks.discard)
             if tasks:                      # drain in-flight before close

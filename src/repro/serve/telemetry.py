@@ -28,12 +28,16 @@ overhead at ≤ 5% ESS/s (``benchmarks/check_serve_regression.py``).
 Clock discipline: span math uses ``time.monotonic()`` exclusively
 (wall clocks step under NTP and would corrupt durations and deadline
 math); wall-clock time appears only once, as the human-readable
-``trace_start_iso`` metadata stamp.
+``trace_start_iso`` metadata stamp.  Spans recorded with
+:meth:`Telemetry.span` are written into a running ``jax.profiler``
+trace as well, on the profiler's own clock, so a profile shows them
+beside the device's operations.
 
 Worked examples live in ``docs/observability.md`` (doctest-checked).
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
 import threading
@@ -280,21 +284,27 @@ def _fmt_labels(labels: dict) -> str:
 class Telemetry:
     """Live recorder: span tracer + metrics registry, one per engine.
 
-    Tracks are Chrome-trace ``tid`` lanes — one per query and one per
-    dispatched group — so spans on the same track nest by time
-    containment when the trace is opened in Perfetto.  All record calls
-    are thread-safe and cheap enough for the round loop; when tracing
-    is off (``Telemetry(trace=False)``) the metrics half still runs.
+    Tracks are Chrome-trace ``tid`` lanes — one per query, one per
+    dispatched group and one for the admission queue's dispatcher — so
+    spans on the same track nest by time containment when the trace is
+    opened in Perfetto.  All record calls are thread-safe and cheap
+    enough for the round loop.  :class:`NullTelemetry` is the off
+    switch.
 
     Timestamps: :func:`monotonic` seconds in, microseconds relative to
     the tracer's birth out (the trace-event ``ts`` contract).
+
+    Two ways to record a span: :meth:`span`, a context manager for work
+    that runs start to end on one thread, which also writes the span
+    into a running ``jax.profiler`` trace; and :meth:`complete`, for an
+    interval known only afterwards or one that crosses threads or
+    ``await``.
     """
 
     enabled = True
 
-    def __init__(self, *, trace: bool = True, metrics: bool = True):
-        self.metrics = MetricsRegistry() if metrics else None
-        self._trace = bool(trace)
+    def __init__(self):
+        self.metrics = MetricsRegistry()
         self._events: list[dict] = []
         self._lock = threading.Lock()
         self._tids: dict[str, int] = {}
@@ -309,8 +319,6 @@ class Telemetry:
     def track(self, name: str) -> int:
         """tid of the named track, creating it (and its Perfetto
         thread-name metadata event) on first use."""
-        if not self._trace:
-            return 0
         with self._lock:
             tid = self._tids.get(name)
             if tid is None:
@@ -322,9 +330,14 @@ class Telemetry:
 
     def complete(self, name: str, tid: int, t0: float, t1: float,
                  **args) -> None:
-        """One finished span [t0, t1] (monotonic seconds) on a track."""
-        if not self._trace:
-            return
+        """One finished span [t0, t1] (monotonic seconds) on a track.
+
+        For intervals whose start is known only afterwards, or that
+        cross threads or ``await`` (the per-query ``query`` / ``wait``
+        / ``plan`` / ``service`` spans, the front end's ``request`` and
+        ``resolve``): these are in the in-memory trace only, not in a
+        ``jax.profiler`` trace — use :meth:`span` where the work runs
+        start to end on one thread."""
         ev = {"name": name, "cat": "serve", "ph": "X", "pid": 1, "tid": tid,
               "ts": self._us(t0), "dur": max((t1 - t0) * 1e6, 0.0)}
         if args:
@@ -332,9 +345,17 @@ class Telemetry:
         with self._lock:
             self._events.append(ev)
 
+    def span(self, name: str, tid: int, **args) -> "_Span":
+        """Context manager timing the enclosed block as a span on a
+        track.  On enter it opens a ``jax.profiler.TraceAnnotation`` of
+        the same name, so a profiled run carries the span on the
+        profiler's own clock, beside the device's operations, on the
+        recording thread; on exit it records the same interval with
+        :meth:`complete` on the monotonic clock.  The block must not
+        cross an ``await`` (annotations of one thread must nest)."""
+        return _Span(self, name, tid, args)
+
     def instant(self, name: str, tid: int, **args) -> None:
-        if not self._trace:
-            return
         ev = {"name": name, "cat": "serve", "ph": "i", "s": "t", "pid": 1,
               "tid": tid, "ts": self._us(monotonic())}
         if args:
@@ -345,8 +366,6 @@ class Telemetry:
     def sample(self, name: str, value: float) -> None:
         """Counter-track sample (Chrome ``ph: "C"``): queue depth, lanes
         busy — rendered as a stepped area chart in Perfetto."""
-        if not self._trace:
-            return
         ev = {"name": name, "cat": "serve", "ph": "C", "pid": 1,
               "ts": self._us(monotonic()), "args": {name: value}}
         with self._lock:
@@ -355,19 +374,16 @@ class Telemetry:
     # -- metrics shorthands -----------------------------------------------
     def count(self, name: str, n: int | float = 1, help: str = "",
               **labels) -> None:
-        if self.metrics is not None:
-            self.metrics.counter(name, help, **labels).inc(n)
+        self.metrics.counter(name, help, **labels).inc(n)
 
     def gauge_set(self, name: str, v: float, help: str = "",
                   **labels) -> None:
-        if self.metrics is not None:
-            self.metrics.gauge(name, help, **labels).set(v)
+        self.metrics.gauge(name, help, **labels).set(v)
 
     def observe(self, name: str, v: float, help: str = "",
                 bins: tuple[float, ...] = DEFAULT_SECONDS_BINS,
                 **labels) -> None:
-        if self.metrics is not None:
-            self.metrics.histogram(name, help, bins, **labels).observe(v)
+        self.metrics.histogram(name, help, bins, **labels).observe(v)
 
     # -- export ------------------------------------------------------------
     def events(self) -> list[dict]:
@@ -393,18 +409,39 @@ class Telemetry:
         }
 
     def metrics_snapshot(self) -> dict:
-        return {} if self.metrics is None else self.metrics.snapshot()
+        return self.metrics.snapshot()
 
     def prometheus(self) -> str:
-        return "" if self.metrics is None else self.metrics.prometheus()
+        return self.metrics.prometheus()
 
     def write_trace(self, path: str) -> None:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f)
 
-    def write_metrics(self, path: str) -> None:
-        with open(path, "w") as f:
-            json.dump(self.metrics_snapshot(), f, indent=2)
+
+class _Span:
+    """One :meth:`Telemetry.span` in flight."""
+
+    __slots__ = ("tel", "name", "tid", "args", "t0", "ann")
+
+    def __init__(self, tel: Telemetry, name: str, tid: int, args: dict):
+        self.tel, self.name, self.tid, self.args = tel, name, tid, args
+
+    def __enter__(self) -> "_Span":
+        # imported here: the JAX-free client imports this module too
+        from jax.profiler import TraceAnnotation
+
+        # the annotation's own cost lies inside the span, so that the
+        # spans of one thread leave only the recording between them
+        self.t0 = monotonic()
+        self.ann = TraceAnnotation(self.name, **self.args)
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ann.__exit__(*exc)
+        self.tel.complete(self.name, self.tid, self.t0, monotonic(),
+                          **self.args)
 
 
 class NullTelemetry(Telemetry):
@@ -419,13 +456,15 @@ class NullTelemetry(Telemetry):
 
     def __init__(self):  # no registry, no event buffer, no lock
         self.metrics = None
-        self._trace = False
 
     def track(self, name: str) -> int:
         return 0
 
     def complete(self, *a, **k) -> None:
         pass
+
+    def span(self, *a, **k) -> contextlib.nullcontext:
+        return _NO_SPAN
 
     def instant(self, *a, **k) -> None:
         pass
@@ -454,6 +493,10 @@ class NullTelemetry(Telemetry):
     def prometheus(self) -> str:
         return ""
 
+
+#: The one span every :meth:`NullTelemetry.span` returns: allocates and
+#: annotates nothing.
+_NO_SPAN = contextlib.nullcontext()
 
 #: Shared no-op recorder — the engine default.  Stateless, so one
 #: instance serves every engine in the process.
