@@ -85,6 +85,84 @@ def site_weights(
     return _weights_from_energies(energies, k=k, table=table, use_iu=use_iu)
 
 
+def _odd_active(h: int, parity: jax.Array) -> jax.Array:
+    """(H, 1) bool: True in the rows whose sites of colour ``parity`` sit
+    in the odd columns ((r + c) % 2 == parity)."""
+    return (((jnp.arange(h) + parity) % 2) == 1)[:, None]
+
+
+def _active_columns(h: int, w2: int, parity: jax.Array) -> jax.Array:
+    """(H, W_even / 2) grid column of each packed site of colour
+    ``parity``."""
+    return 2 * jnp.arange(w2)[None, :] + _odd_active(h, parity)
+
+
+def _split_colours(x: jax.Array, parity: jax.Array, *, w_axis: int = -1,
+                  fill=0) -> tuple[jax.Array, jax.Array]:
+    """(this colour, the other colour) of a grid-shaped ``x``, each with
+    W_even / 2 columns: the W axis (``w_axis``, right after the H axis)
+    is padded to even with ``fill``, and each pair of columns gives its
+    ``parity`` site to the first array and the other site to the second.
+    Only pads, strided slices and selects: no gather."""
+    w_axis %= x.ndim
+    pad = [(0, 0)] * x.ndim
+    pad[w_axis] = (0, x.shape[w_axis] % 2)
+    xp = jnp.pad(x, pad, constant_values=fill)
+    even = jax.lax.slice_in_dim(xp, 0, None, 2, axis=w_axis)
+    odd = jax.lax.slice_in_dim(xp, 1, None, 2, axis=w_axis)
+    sel = _odd_active(x.shape[w_axis - 1], parity).reshape(
+        (-1,) + (1,) * (x.ndim - w_axis))
+    return jnp.where(sel, odd, even), jnp.where(sel, even, odd)
+
+
+def _merge_colours(mine: jax.Array, other: jax.Array, parity: jax.Array,
+                  w: int) -> jax.Array:
+    """Inverse of :func:`_split_colours` on a (..., H, W_even / 2) pair:
+    the (..., H, w) grid, pad column dropped."""
+    sel = _odd_active(mine.shape[-2], parity)
+    even = jnp.where(sel, other, mine)
+    odd = jnp.where(sel, mine, other)
+    # interleave by interior padding: even columns at 0, 2, ..., odd at
+    # 1, 3, ..., each zero where the other sits
+    keep = [(0, 0, 0)] * (mine.ndim - 1)
+    zero = jnp.zeros((), mine.dtype)
+    grid = (jax.lax.pad(even, zero, keep + [(0, 1, 1)])
+            + jax.lax.pad(odd, zero, keep + [(1, 0, 1)]))
+    return grid[..., :w]
+
+
+def _colour_pair_energy(other: jax.Array, parity: jax.Array, w: int,
+                       pairwise: jax.Array) -> jax.Array:
+    """(B, H, W_even / 2, L) energy of each candidate label at the sites
+    of colour ``parity`` against their 4 neighbours, read from the other
+    colour's labels ``other`` (packed by :func:`_split_colours`).
+
+    Every neighbour of a site is of the other colour: up and down sit in
+    the same column pair, left and right in this pair or the one before
+    (after) as the row's colour sits in the even (odd) columns.  Edge
+    sites see only their in-grid neighbours (free boundary), and the
+    pad column of an odd W is no neighbour.  The four terms are summed in
+    :func:`neighbor_pair_energy`'s order, so the energies are bitwise
+    those of the full grid at the same sites."""
+    pw = pairwise  # (L, L); pw[l, m] = energy of candidate l next to m
+    h, w2 = other.shape[-2:]
+    odd = _odd_active(h, parity)
+    row = jnp.arange(h)[:, None]
+    col = _active_columns(h, w2, parity)
+
+    def nbr(labels, valid):
+        contrib = jnp.take(pw.T, labels, axis=0)  # pw[l, labels]
+        return contrib * valid[..., None]
+
+    e = jnp.zeros(other.shape + (pw.shape[0],), jnp.float32)
+    up = nbr(jnp.roll(other, 1, axis=-2), row > 0)
+    down = nbr(jnp.roll(other, -1, axis=-2), row < h - 1)
+    left = nbr(jnp.where(odd, other, jnp.roll(other, 1, axis=-1)), col > 0)
+    right = nbr(jnp.where(odd, jnp.roll(other, -1, axis=-1), other),
+                col < w - 1)
+    return e + up + down + left + right
+
+
 @partial(jax.jit, static_argnames=("k", "use_iu", "sampler", "mesh"))
 def checkerboard_halfstep(
     key: jax.Array,
@@ -102,6 +180,13 @@ def checkerboard_halfstep(
 ) -> tuple[jax.Array, SweepStats]:
     """Resample all sites of one checkerboard color, all chains at once.
 
+    Only that colour is walked: :func:`_split_colours` packs its sites
+    into (B, H, W_even / 2) (W padded to even; the pad column never
+    updates and is no neighbour), and the energies, weights, bit words,
+    KY walk and bit sums all run on the packed half, which is written
+    back into its own sites alone.  The sampler sees B·H·W_even / 2
+    lanes, not B·H·W.
+
     ``clamp`` marks evidence (observed-pixel) sites: they are skipped by
     the update and by the bit accounting, but their *fixed* labels still
     sit in ``labels`` and therefore keep contributing pairwise energy to
@@ -118,28 +203,24 @@ def checkerboard_halfstep(
     KY walk through the fused kernel (``kernels/fused_sweep.py``): the
     per-site energies become negated log-weights (negation is exact, so
     ``-(e - min e)`` and ``(-e) - max(-e)`` feed the exp LUT the same
-    floats) and the result is bitwise-identical to the XLA path.
+    floats) and the result is bitwise-identical to the XLA path: both
+    receive the same packed rows and the same key.
     """
-    b, h, w = labels.shape
+    w = labels.shape[-1]
     l = unary.shape[-1]
+    mine, other = _split_colours(labels, parity)
     # named scopes: the weight path (energies and, under xla, the IU-exp
     # table) and the KY walk (under pallas, the fused kernel with its
     # table) are found by name in a profile's op metadata
     with jax.named_scope("weights"):
-        if beta is None:
-            energies = None  # keep the β-free trace byte-identical
-        else:
-            energies = unary[None] + neighbor_pair_energy(labels, pairwise)
+        energies = (_split_colours(unary, parity, w_axis=1)[0][None]
+                    + _colour_pair_energy(other, parity, w, pairwise))
+        if beta is not None:
             bb = jnp.asarray(beta, energies.dtype)
             energies = energies * (
                 bb[:, None, None, None] if bb.ndim == 1 else bb)
         if sampler == "pallas":
-            if energies is None:
-                energies = unary[None] + neighbor_pair_energy(
-                    labels, pairwise)
             logw = (-energies).reshape((-1, l))
-        elif energies is None:
-            wts = site_weights(labels, unary, pairwise, k=k, use_iu=use_iu)
         else:
             wts = _weights_from_energies(energies, k=k, use_iu=use_iu)
     with jax.named_scope("ky_walk"):
@@ -148,15 +229,16 @@ def checkerboard_halfstep(
                                      table=_EXP, mesh=mesh)
         else:
             res = ky_sample(key, wts.reshape((-1, l)))
-    new = res.sample.reshape((b, h, w))
-    mask = (((jnp.arange(h)[:, None] + jnp.arange(w)[None, :]) % 2) == parity)[None]
+    # the sites that update: real columns (not the pad) that are not clamped
+    mask = _active_columns(*mine.shape[-2:], parity) < w
     if clamp is not None:
-        mask = mask & ~(clamp if clamp.ndim == 3 else clamp[None])
-    labels = jnp.where(mask, new, labels)
+        mask = mask & ~_split_colours(clamp, parity, fill=True)[0]
+    new = jnp.where(mask, res.sample.reshape(mine.shape), mine)
+    labels = _merge_colours(new, other, parity, w)
     zero = jnp.zeros((), jnp.int32)
     stats = SweepStats(
-        bits_used=jnp.sum(jnp.where(mask, res.bits_used.reshape(labels.shape), zero)),
-        attempts=jnp.sum(jnp.where(mask, res.attempts.reshape(labels.shape), zero)),
+        bits_used=jnp.sum(jnp.where(mask, res.bits_used.reshape(mine.shape), zero)),
+        attempts=jnp.sum(jnp.where(mask, res.attempts.reshape(mine.shape), zero)),
     )
     return labels, stats
 

@@ -209,6 +209,7 @@ class GroupRun:
         self.spr = engine.sweeps_per_round
         self.burn_rounds = math.ceil(engine.burn_in / self.spr)
         self.n_free = self.family.n_free(self.prog)
+        self.ky_lanes = self.family.ky_lanes(self.prog)
         self.n_vars = self.family.n_vars(self.prog)
         # groups are mode-homogeneous: ``answer_batch`` and the admission
         # queue fold the mode into the group key, so one group is either
@@ -392,11 +393,15 @@ class GroupRun:
             # every lane updates every free site each sweep, vacant and
             # pad lanes included: the denominator of bits_per_sample
             updates = self.bt * self.n_free * self.spr
+            # the Knuth-Yao walks the round program ran, pad and clamped
+            # lanes included: over site_updates, the sweep's walk overhead
+            ky_lanes = self.bt * self.ky_lanes * self.spr
             tel.complete(
                 "round", tid, t_round0, t_round1,
                 sweeps=self.spr, lanes_busy=busy * self.c,
                 lanes_vacant=(len(self.slots) - busy) * self.c,
-                retired=len(retired), site_updates=updates, bits=bits,
+                retired=len(retired), site_updates=updates,
+                ky_lanes=ky_lanes, bits=bits,
                 **({"ess": ess} if ess else {}))
             tel.sample("lanes_busy", now_busy * self.c)
             tel.count("serve_rounds_total", help="scheduling rounds run")
@@ -404,6 +409,8 @@ class GroupRun:
                       help="Gibbs sweeps run (all groups, incl. burn-in)")
             tel.count("serve_site_updates_total", updates,
                       help="site updates run, every lane (incl. burn-in)")
+            tel.count("serve_ky_lanes_total", ky_lanes,
+                      help="Knuth-Yao walks run, every lane (incl. burn-in)")
             tel.count("serve_random_bits_total", bits,
                       help="random bits the Knuth-Yao walks consumed")
             tel.gauge_set("serve_lanes_busy", now_busy * self.c,

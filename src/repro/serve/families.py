@@ -347,6 +347,10 @@ class BayesNetFamily:
     def n_free(self, prog) -> int:
         return len(prog.free_nodes)
 
+    def ky_lanes(self, prog) -> int:
+        """Knuth-Yao walks one chain runs per sweep: each colour's nodes."""
+        return sum(len(plan.nodes) for plan in prog.plans)
+
     def plan_salt(self, model):
         """BN plans are fully determined by (name, pattern, knobs)."""
         return None
@@ -487,6 +491,13 @@ class MrfFamily:
     def n_free(self, prog) -> int:
         return prog.n_free
 
+    def ky_lanes(self, prog) -> int:
+        """Knuth-Yao walks one chain runs per sweep: the two half-steps
+        each walk one colour packed to H x W_even / 2 lanes, clamped
+        sites and the pad column of an odd W included."""
+        h, w = prog.shape
+        return h * (w + w % 2)
+
     def plan_salt(self, model):
         """MRF plans are fully determined by (name, pattern, knobs)."""
         return None
@@ -593,6 +604,10 @@ class IsingFamily:
 
     def n_free(self, prog) -> int:
         return prog.n_free
+
+    def ky_lanes(self, prog) -> int:
+        """Knuth-Yao walks one chain runs per sweep: each colour's nodes."""
+        return sum(len(plan.nodes) for plan in prog.plans)
 
     def plan_salt(self, model):
         """Sparse plans are shaped by the graph itself (coloring, degree

@@ -1,8 +1,11 @@
 """PGM substrate: coloring invariants, Gibbs convergence to exact
 marginals, compiler-chain correctness, MRF energy descent."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from _hyp import given, settings, st
 
 from repro.pgm import (
@@ -121,6 +124,142 @@ class TestMRFGibbs:
         n_samples = 16 * 16 * 5
         bits = float(stats.bits_used) / n_samples
         assert 1.0 < bits < 8.0  # binary labels: H+2 <= 3ish
+
+
+PACKED_CASES = [
+    # (H, W), clamp mask shape, per-lane beta
+    ((6, 8), None, False),
+    ((6, 8), "grid", False),
+    ((6, 8), "lanes", True),
+    ((5, 7), None, False),
+    ((5, 7), "grid", True),
+    ((5, 7), "lanes", False),
+]
+PACKED_IDS = [f"{h}x{w}-{c}-{'beta' if b else 'gibbs'}"
+              for (h, w), c, b in PACKED_CASES]
+
+
+@functools.lru_cache(maxsize=None)
+def _packed_halfstep(shape, clamp_kind, with_beta, parity):
+    """One traced half-step with the sampler's input and output kept:
+    (labels in, labels out, stats, weights handed to ky_sample, its
+    KYResult, clamp as (B, H, W), beta or None, unary, pairwise)."""
+    from repro.pgm import gibbs
+
+    h, w = shape
+    b, n_labels = 2, 3
+    rng = np.random.default_rng(h * 100 + w)
+    unary = jnp.asarray(rng.normal(0, 1, (h, w, n_labels)), jnp.float32)
+    pairwise = jnp.asarray(rng.random((n_labels, n_labels)), jnp.float32)
+    labels = jnp.asarray(rng.integers(0, n_labels, (b, h, w)), jnp.int32)
+    clamp = None
+    if clamp_kind == "grid":
+        clamp = jnp.asarray(rng.random((h, w)) < 0.3)
+    elif clamp_kind == "lanes":
+        clamp = jnp.asarray(rng.random((b, h, w)) < 0.3)
+    beta = jnp.asarray([0.5, 2.0], jnp.float32) if with_beta else None
+    seen, ky_sample = {}, gibbs.ky_sample
+
+    def spy(key, wts, **kw):
+        seen["res"] = res = ky_sample(key, wts, **kw)
+        seen["wts"] = wts
+        return res
+
+    def run(key, labels):
+        out, stats = gibbs.checkerboard_halfstep.__wrapped__(
+            key, labels, unary, pairwise, jnp.int32(parity), clamp=clamp,
+            beta=beta)
+        return out, stats, seen["wts"], seen["res"]
+
+    gibbs.ky_sample = spy
+    try:
+        out, stats, wts, res = jax.jit(run)(jax.random.PRNGKey(7), labels)
+    finally:
+        gibbs.ky_sample = ky_sample
+    full_clamp = (np.zeros((b, h, w), bool) if clamp is None
+                  else np.broadcast_to(np.asarray(clamp), (b, h, w)))
+    return dict(labels=np.asarray(labels), out=np.asarray(out),
+                stats=stats, wts=np.asarray(wts), res=res,
+                clamp=full_clamp, beta=beta, unary=unary,
+                pairwise=pairwise)
+
+
+def _active_sites(h, w, parity):
+    """(row, packed column, grid column) of each packed lane of a row."""
+    for r in range(h):
+        for j in range((w + w % 2) // 2):
+            yield r, j, 2 * j + (r + parity) % 2
+
+
+class TestPackedHalfstep:
+    """``checkerboard_halfstep`` walks only the active colour, packed to
+    (B, H, W_even / 2) lanes; odd widths carry a pad column."""
+
+    @pytest.mark.parametrize("case", PACKED_CASES, ids=PACKED_IDS)
+    def test_active_weights_equal_full_grid_probe(self, case):
+        from repro.pgm.gibbs import (
+            _weights_from_energies, neighbor_pair_energy, site_weights)
+
+        (h, w), clamp_kind, with_beta = case
+        for parity in (0, 1):
+            got = _packed_halfstep((h, w), clamp_kind, with_beta, parity)
+            lab = jnp.asarray(got["labels"])
+            if got["beta"] is None:
+                full = site_weights(lab, got["unary"], got["pairwise"])
+            else:
+                e = got["unary"][None] + neighbor_pair_energy(
+                    lab, got["pairwise"])
+                full = _weights_from_energies(
+                    e * got["beta"][:, None, None, None])
+            full = np.asarray(full)
+            packed = got["wts"].reshape(2, h, -1, full.shape[-1])
+            for r, j, c in _active_sites(h, w, parity):
+                if c < w:
+                    np.testing.assert_array_equal(packed[:, r, j],
+                                                  full[:, r, c])
+
+    @pytest.mark.parametrize("case", PACKED_CASES, ids=PACKED_IDS)
+    def test_only_active_free_sites_change(self, case):
+        (h, w), clamp_kind, with_beta = case
+        for parity in (0, 1):
+            got = _packed_halfstep((h, w), clamp_kind, with_beta, parity)
+            out, lab = got["out"], got["labels"]
+            assert out.shape == lab.shape and out.dtype == lab.dtype
+            active = (np.add.outer(np.arange(h), np.arange(w)) % 2
+                      == parity)[None]
+            frozen = ~active | got["clamp"]
+            np.testing.assert_array_equal(out[frozen], lab[frozen])
+            # the free active sites take the sampler's draw
+            sample = np.asarray(got["res"].sample).reshape(2, h, -1)
+            for r, j, c in _active_sites(h, w, parity):
+                if c < w:
+                    free = ~got["clamp"][:, r, c]
+                    np.testing.assert_array_equal(
+                        out[free, r, c], sample[free, r, j])
+
+    @pytest.mark.parametrize("case", PACKED_CASES, ids=PACKED_IDS)
+    def test_stats_sum_active_free_sites(self, case):
+        (h, w), clamp_kind, with_beta = case
+        for parity in (0, 1):
+            got = _packed_halfstep((h, w), clamp_kind, with_beta, parity)
+            bits = np.asarray(got["res"].bits_used).reshape(2, h, -1)
+            att = np.asarray(got["res"].attempts).reshape(2, h, -1)
+            want_bits = want_att = 0
+            for r, j, c in _active_sites(h, w, parity):
+                if c < w:
+                    free = ~got["clamp"][:, r, c]
+                    want_bits += int(bits[free, r, j].sum())
+                    want_att += int(att[free, r, j].sum())
+            assert int(got["stats"].bits_used) == want_bits
+            assert int(got["stats"].attempts) == want_att
+            assert want_att > 0
+
+    @pytest.mark.parametrize("case", PACKED_CASES, ids=PACKED_IDS)
+    def test_sampler_walks_half_the_padded_grid(self, case):
+        (h, w), clamp_kind, with_beta = case
+        for parity in (0, 1):
+            got = _packed_halfstep((h, w), clamp_kind, with_beta, parity)
+            assert got["wts"].shape == (2 * h * (w + w % 2) // 2, 3)
 
 
 class TestSweepStatsOverflow:

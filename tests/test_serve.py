@@ -379,14 +379,22 @@ class TestPallasSampler:
             mk("xla"), mk("pallas"), jax.random.PRNGKey(1), x, jnp.int32(0))
 
     def test_mrf_round_runner_bitwise(self):
+        # an even width, and an odd one (a pad column in the packed
+        # colour) with clamped sites
         rng = np.random.default_rng(0)
-        mrf_prog = compile_mrf(MRFGrid.potts(
-            rng.normal(0, 1, (6, 6, 3)).astype(np.float32), beta=0.6))
-        mk = lambda s: make_mrf_round_runner(
-            mrf_prog, sweeps_per_round=4, thin=1, use_iu=True, sampler=s)
-        x = init_mrf_states(jax.random.PRNGKey(0), mrf_prog, 2)
-        self._assert_rounds_identical(
-            mk("xla"), mk("pallas"), jax.random.PRNGKey(2), x, jnp.int32(0))
+        for (h, w), observed in (((6, 6), ()), ((5, 7), (0, 8, 13, 34))):
+            mrf_prog = compile_mrf(MRFGrid.potts(
+                rng.normal(0, 1, (h, w, 3)).astype(np.float32), beta=0.6),
+                observed=observed)
+            mk = lambda s: make_mrf_round_runner(
+                mrf_prog, sweeps_per_round=4, thin=1, use_iu=True,
+                sampler=s)
+            x = init_mrf_states(
+                jax.random.PRNGKey(0), mrf_prog, 2,
+                jnp.ones((2, len(observed)), jnp.int32) if observed else None)
+            self._assert_rounds_identical(
+                mk("xla"), mk("pallas"), jax.random.PRNGKey(2), x,
+                jnp.int32(0))
 
     def test_ising_round_runner_bitwise(self):
         prog = compile_factor_graph(networks.ising_torus(4, beta=0.4))

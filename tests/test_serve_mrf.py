@@ -236,36 +236,38 @@ class TestMrfQueueServing:
         """The acceptance bit: masked-MRF queries served through the
         admission queue (bucketed by mask pattern, packed into one
         GroupRun) are bit-identical to ``answer_batch`` over the same
-        traffic with the same seed."""
-        mrf, _ = networks.penguin_task(h=8, w=8)
-        mask_a, values = _scribble(8, 8, seed=0, frac=0.2)
-        mask_b, _ = _scribble(8, 8, seed=1, frac=0.2)
-        traffic = [
-            MrfQuery("p", mask_a, values, _free_sites(mask_a, 2),
-                     n_samples=2048),
-            MrfQuery("p", mask_b, values, _free_sites(mask_b, 1),
-                     n_samples=1024),
-            MrfQuery("p", mask_a, 1 - values, _free_sites(mask_a, 2),
-                     n_samples=2048),
-        ]
-        kw = dict(chains_per_query=8, burn_in=16, max_rounds=8)
-        ref = PosteriorEngine({"p": mrf}, **kw, seed=11).answer_batch(traffic)
-        eng = PosteriorEngine({"p": mrf}, **kw, seed=11)
-        queue = AdmissionQueue(eng, max_wait_ms=3_600_000.0,
-                               max_group_lanes=len(traffic) * 8)
-        try:
-            handles = [queue.submit(q) for q in traffic]
-            queue.flush()
-            streamed = [h.result(timeout=600) for h in handles]
-        finally:
-            queue.close()
-        # two mask_a queries share one bucket/plan; mask_b gets its own
-        assert eng.cache.stats.misses == 2
-        for a, b in zip(ref, streamed):
-            assert a.n_samples == b.n_samples and a.rhat == b.rhat
-            assert set(a.marginals) == set(b.marginals)
-            for k in a.marginals:
-                assert np.array_equal(a.marginals[k], b.marginals[k])
+        traffic with the same seed; on an even and an odd width."""
+        for h, w in ((8, 8), (7, 9)):
+            mrf, _ = networks.penguin_task(h=h, w=w)
+            mask_a, values = _scribble(h, w, seed=0, frac=0.2)
+            mask_b, _ = _scribble(h, w, seed=1, frac=0.2)
+            traffic = [
+                MrfQuery("p", mask_a, values, _free_sites(mask_a, 2),
+                         n_samples=2048),
+                MrfQuery("p", mask_b, values, _free_sites(mask_b, 1),
+                         n_samples=1024),
+                MrfQuery("p", mask_a, 1 - values, _free_sites(mask_a, 2),
+                         n_samples=2048),
+            ]
+            kw = dict(chains_per_query=8, burn_in=16, max_rounds=8)
+            ref = PosteriorEngine({"p": mrf}, **kw,
+                                  seed=11).answer_batch(traffic)
+            eng = PosteriorEngine({"p": mrf}, **kw, seed=11)
+            queue = AdmissionQueue(eng, max_wait_ms=3_600_000.0,
+                                   max_group_lanes=len(traffic) * 8)
+            try:
+                handles = [queue.submit(q) for q in traffic]
+                queue.flush()
+                streamed = [h.result(timeout=600) for h in handles]
+            finally:
+                queue.close()
+            # two mask_a queries share one bucket/plan; mask_b its own
+            assert eng.cache.stats.misses == 2
+            for a, b in zip(ref, streamed):
+                assert a.n_samples == b.n_samples and a.rhat == b.rhat
+                assert set(a.marginals) == set(b.marginals)
+                for k in a.marginals:
+                    assert np.array_equal(a.marginals[k], b.marginals[k])
 
     def test_mixed_family_batch(self):
         """One batch spanning a BayesNet and an MRF comes back in

@@ -439,6 +439,42 @@ class TestEngineSpans:
             == snap["serve_site_updates_total"]
         assert all(r.bits_per_sample > 0 for r in results)
 
+    def test_ky_lanes_counter_adds_up_over_rounds(self, served_asia):
+        # Bayesian network: each sweep walks every colour's nodes once
+        engine, queue, *_ = served_asia
+        snap = engine.telemetry.metrics_snapshot()
+        rounds = _spans(engine.telemetry, ("round",))
+        assert all("ky_lanes" in a for *_, a in rounds)
+        assert sum(a["ky_lanes"] for *_, a in rounds) \
+            == snap["serve_ky_lanes_total"]
+        assert snap["serve_ky_lanes_total"] == sum(
+            r.bt * sum(len(p.nodes) for p in r.prog.plans) * r.sweeps_done
+            for r in queue.runs)
+
+    def test_mrf_round_walks_one_packed_colour_per_half_step(self):
+        # a 5 x 7 grid walks 5 x 8 lanes a chain a sweep: two half-steps
+        # of 5 x 4, the pad column of the odd width included
+        from repro.serve import MrfQuery
+
+        mrf, truth = networks.penguin_task(h=5, w=7)
+        mask = np.zeros((5, 7), bool)
+        mask[2, 1:4] = True
+        engine = PosteriorEngine({"p": mrf}, telemetry=Telemetry(),
+                                 chains_per_query=4, burn_in=4,
+                                 max_rounds=2, seed=0)
+        traffic = [MrfQuery("p", mask, np.where(mask, truth, 0),
+                            query_sites=((0, 0), (4, 6)), n_samples=64)]
+        queue, *_ = _serve_through_queue(engine, traffic)
+        rounds = _spans(engine.telemetry, ("round",))
+        assert rounds
+        for *_, a in rounds:
+            assert a["ky_lanes"] == queue.runs[0].bt * 5 * 8 * a["sweeps"]
+            assert a["site_updates"] == \
+                queue.runs[0].bt * (35 - 3) * a["sweeps"]
+        snap = engine.telemetry.metrics_snapshot()
+        assert snap["serve_ky_lanes_total"] == sum(
+            a["ky_lanes"] for *_, a in rounds)
+
     def test_one_group_bits_per_update_is_bits_per_sample(self):
         # every query retires at the cap in the same round, so each
         # answer's bits_per_sample covers the whole group run

@@ -3,9 +3,10 @@
 Every other test runs the kernel in the Pallas interpreter, which accepts
 ops the TPU compiler (Mosaic) refuses.  These tests compile
 ``fused_gibbs_sample`` with ``interpret=False`` for a v5e chip that is
-described, not attached, at the shapes the MRF round runner hands it:
-the Penguin grid (500x333 sites, 2 labels) and the Art grid (288x384
-sites, 16 labels), each over 8 chain lanes, on one chip and with the
+described, not attached, at the shapes the MRF round runner hands it
+(one checkerboard colour, H x W_even / 2 lanes a chain): the Penguin
+grid (500x333 sites, 2 labels) and the Art grid (288x384 sites, 16
+labels), each over 8 chain lanes, on one chip and with the
 lanes sharded over a 4-chip serve mesh.  Nothing runs; the compile
 raises what the chip's compiler would raise, including a kernel tile
 that overflows VMEM, a program that overflows HBM, or a kernel left for
@@ -49,7 +50,7 @@ def topo():
 
 
 def _compile(cfg, lane_sharding, key_sharding, mesh=None):
-    lanes = CHAIN_LANES * cfg.height * cfg.width
+    lanes = CHAIN_LANES * cfg.height * (cfg.width + cfg.width % 2) // 2
     args = (jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=key_sharding),
             jax.ShapeDtypeStruct((lanes, cfg.n_labels), jnp.float32,
                                  sharding=lane_sharding),
